@@ -14,6 +14,7 @@ vmap so MoE expert banks decompose in one call.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple
 
@@ -118,13 +119,74 @@ def randomized_svd(w: jax.Array, rank: int, *, oversample: int = 8,
     return SVDFactors(w0.astype(orig_dtype), w1.astype(orig_dtype))
 
 
+@functools.partial(jax.jit, static_argnames="wide")
+def _gram(w: jax.Array, wide: bool) -> jax.Array:
+    """f32 Gram matrix of ``w``'s short side: W W^T when ``wide``."""
+    a = w if wide else w.T
+    return jnp.dot(a, a.T, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+@functools.partial(jax.jit, static_argnames="wide")
+def _balanced(w: jax.Array, vec: jax.Array, sig: jax.Array,
+              wide: bool) -> SVDFactors:
+    """Balanced factors from the short side's top singular vectors
+    ``vec`` (n, r) and values ``sig`` (r,): the long side's vectors come
+    from one product with ``w``.  Directions at or below the f32 Gram
+    noise floor get zero factors (they carry no signal)."""
+    root = jnp.sqrt(sig)
+    floor = sig[0] * 1e-3
+    inv = jnp.where(sig > floor, 1.0 / jnp.where(sig > floor, root, 1.0),
+                    0.0)
+    hi = jax.lax.Precision.HIGHEST
+    # the product reads w in its own dtype: no f32 copy of a big matrix
+    if wide:                                        # vec = U_r
+        w0 = vec * root[None, :]
+        w1 = inv[:, None] * jnp.dot(vec.astype(w.dtype).T, w, precision=hi,
+                                    preferred_element_type=jnp.float32)
+    else:                                           # vec = V_r
+        w0 = jnp.dot(w, vec.astype(w.dtype), precision=hi,
+                     preferred_element_type=jnp.float32) * inv[None, :]
+        w1 = root[:, None] * vec.T
+    return SVDFactors(w0.astype(w.dtype), w1.astype(w.dtype))
+
+
+def gram_svd_decompose(w: jax.Array, rank: int) -> SVDFactors:
+    """The balanced factors of :func:`svd_decompose`, computed through
+    the Gram matrix of the short side.
+
+    ``W W^T`` (or ``W^T W``) is formed on ``w``'s device, its symmetric
+    eigendecomposition runs on the host (LAPACK), and one more product
+    on the device gives the long side.  The device runs only matmuls:
+    XLA's SVD and eigh compile for many minutes per shape on a TPU, a
+    matmul in seconds.  Leading (layer / expert) dims decompose one
+    matrix at a time, which also bounds device temporaries to one
+    matrix.  Accurate to f32 rounding of the Gram matrix — ample for
+    factors stored in bf16 or f32.
+    """
+    if w.ndim > 2:
+        parts = [gram_svd_decompose(w[i], rank) for i in range(w.shape[0])]
+        return SVDFactors(jnp.stack([f.w0 for f in parts]),
+                          jnp.stack([f.w1 for f in parts]))
+    import scipy.linalg
+    wide = w.shape[0] <= w.shape[1]
+    lam, vec = scipy.linalg.eigh(np.asarray(_gram(w, wide)), driver="evd")
+    r = min(rank, lam.shape[0])
+    lam, vec = lam[::-1][:r], vec[:, ::-1][:, :r]   # descending
+    sig = np.sqrt(np.maximum(lam, 0.0)).astype(np.float32)
+    return _balanced(w, jnp.asarray(np.ascontiguousarray(vec)),
+                     jnp.asarray(sig), wide)
+
+
 def decompose_auto(w: jax.Array, rank: int, *, randomized_threshold: int = 4096,
                    key: jax.Array | None = None) -> SVDFactors:
-    """Full SVD for small matrices, randomized for big ones."""
+    """Gram-matrix SVD (:func:`gram_svd_decompose`) for matrices whose
+    short side is at most ``randomized_threshold``, randomized SVD for
+    bigger ones at low rank."""
     c, s = int(w.shape[-2]), int(w.shape[-1])
     if min(c, s) > randomized_threshold and rank < min(c, s) // 4:
         return randomized_svd(w, rank, key=key)
-    return svd_decompose(w, rank)
+    return gram_svd_decompose(w, rank)
 
 
 def host_svd_decompose(w: np.ndarray, rank: int) -> tuple[np.ndarray, np.ndarray]:

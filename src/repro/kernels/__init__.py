@@ -8,6 +8,10 @@
   int8/fp8 factor tiles dequantized in VMEM (see repro/quant/).
 * :mod:`repro.kernels.ops` — jit'd wrappers with padding + dispatch.
 * :mod:`repro.kernels.ref` — pure-jnp oracles for the allclose tests.
+* :mod:`repro.kernels.tpu` — the compiler settings every kernel shares
+  (the one VMEM budget) and the interpret-mode switch.
 
-Validated with ``interpret=True`` on CPU; compiled path targets TPU.
+Run in interpret mode on the CPU backend (the parity tests) and compiled
+everywhere else; ``tests/test_tpu_compile.py`` compiles the main path's
+kernels for a described v5e.
 """

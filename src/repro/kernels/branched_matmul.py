@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.lowrank_matmul import CompilerParams
+from repro.kernels import tpu
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -79,14 +79,16 @@ def branched_matmul(x: jax.Array, u: jax.Array, xc: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, s), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=tpu.compiler_params(
+            "parallel", "parallel", "arbitrary"),
     )(x, u, xc, v)
 
 
 def vmem_bytes(m_block: int, c: int, r1: int, r2: int, s_block: int,
                dtype_bytes: int = 2) -> int:
-    return (m_block * c * dtype_bytes
-            + c * r1 * dtype_bytes + r1 * r2 * dtype_bytes
-            + r2 * s_block * dtype_bytes
-            + 2 * m_block * s_block * (dtype_bytes + 4))
+    blocks = (m_block * c * dtype_bytes
+              + c * r1 * dtype_bytes + r1 * r2 * dtype_bytes
+              + r2 * s_block * dtype_bytes
+              + m_block * s_block * dtype_bytes)      # out block
+    return (tpu.BUFFERS * blocks
+            + m_block * s_block * (dtype_bytes + 2 * 4))  # acc + contrib

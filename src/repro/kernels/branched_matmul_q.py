@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.lowrank_matmul import CompilerParams
+from repro.kernels import tpu
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -103,8 +103,8 @@ def branched_matmul_q(x: jax.Array, u_q: jax.Array, u_scale: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, s), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=tpu.compiler_params(
+            "parallel", "parallel", "arbitrary"),
     )(x, u_q, u_scale, xc_q, xc_scale, v_q, v_scale)
 
 
@@ -116,8 +116,9 @@ def vmem_bytes(m_block: int, c: int, r1: int, r2: int, s_block: int,
     activation-width copies, and the f32 branch accumulator + out block.
     """
     deq = (c * r1 + r1 * r2 + r2 * s_block) * act_bytes
-    return (m_block * c * act_bytes
-            + (c * r1 + r1 * r2 + r2 * s_block) * q_bytes
-            + (r1 + r2 + s_block) * 4
-            + deq
-            + 2 * m_block * s_block * (act_bytes + 4))
+    blocks = (m_block * c * act_bytes
+              + (c * r1 + r1 * r2 + r2 * s_block) * q_bytes
+              + (r1 + r2 + s_block) * 4
+              + m_block * s_block * act_bytes)        # out block
+    return (tpu.BUFFERS * blocks + deq
+            + m_block * s_block * (act_bytes + 2 * 4))  # acc + contrib

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.lowrank_matmul import CompilerParams
+from repro.kernels import tpu
 from repro.kernels.lowrank_matmul_qa import quantize_rows
 
 DEFAULT_BM = 256
@@ -111,8 +111,8 @@ def branched_matmul_qa(x: jax.Array, u_q: jax.Array, u_scale: jax.Array,
                         pltpu.VMEM((bm, c), jnp.int8),
                         pltpu.VMEM((bm, 1), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=tpu.compiler_params(
+            "parallel", "parallel", "arbitrary"),
     )(x, u_q, u_scale, xc_q, xc_scale, v_q, v_scale)
 
 
@@ -125,10 +125,12 @@ def vmem_bytes(m_block: int, c: int, r1: int, r2: int, s_block: int,
     transient int8/f32 rank intermediates, and the f32 branch
     accumulator + out block.
     """
-    return (m_block * c * act_bytes                    # x block
+    blocks = (m_block * c * act_bytes                  # x block
+              + (c * r1 + r1 * r2 + r2 * s_block) * q_bytes
+              + (r1 + r2 + s_block) * 4                # channel scales
+              + m_block * s_block * act_bytes)         # out block
+    return (tpu.BUFFERS * blocks
             + m_block * c + m_block * 4                # int8 x scratch + scales
-            + (c * r1 + r1 * r2 + r2 * s_block) * q_bytes
-            + (r1 + r2 + s_block) * 4                  # channel scales
             + m_block * (r1 + r2) * (1 + 4)            # int8+f32 intermediates
             + 2 * m_block * 4                          # h1/h2 row scales
-            + m_block * s_block * (act_bytes + 2 * 4))  # out + acc + contrib
+            + m_block * s_block * 2 * 4)               # acc + contrib

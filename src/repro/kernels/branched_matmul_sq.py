@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.lowrank_matmul import CompilerParams
+from repro.kernels import tpu
 from repro.kernels.lowrank_matmul_sq import expand_tile
 
 DEFAULT_BM = 256
@@ -109,8 +109,8 @@ def branched_matmul_sq(x: jax.Array, u_sp: jax.Array, u_idx: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, s), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=tpu.compiler_params(
+            "parallel", "parallel", "arbitrary"),
     )(x, u_sp, u_idx, u_scale, xc_q, xc_scale, v_sp, v_idx, v_scale)
 
 
@@ -125,8 +125,9 @@ def vmem_bytes(m_block: int, c: int, r1: int, r2: int, s_block: int,
     packed = (c // 2) * r1 + (r2 // 2) * s_block     # kept u/v values
     meta = (c // 2) + (r2 // 2)                      # int8 indices
     expanded = (c * r1 + r1 * r2 + r2 * s_block) * (4 + act_bytes)
-    return (m_block * c * act_bytes
-            + packed * q_bytes + r1 * r2 * q_bytes + meta
-            + (r1 + r2 + s_block) * 4
-            + expanded
-            + 2 * m_block * s_block * (act_bytes + 4))
+    blocks = (m_block * c * act_bytes
+              + packed * q_bytes + r1 * r2 * q_bytes + meta
+              + (r1 + r2 + s_block) * 4
+              + m_block * s_block * act_bytes)        # out block
+    return (tpu.BUFFERS * blocks + expanded
+            + m_block * s_block * (act_bytes + 2 * 4))  # acc + contrib

@@ -30,9 +30,11 @@ final output (they change block to block), so each block's context
 contribution is scaled before accumulation — O(G*D) multiplies per
 block in place of O(bs*D) dequantization.
 
-Grid: ``(B_slots, KV_heads, blocks_per_slot)`` with the block dim
-innermost (arbitrary); slots and heads are parallel.  The GQA group of
-G = H/KH query heads rides as rows of the q/out tiles.
+Grid: ``(B_slots, blocks_per_slot)`` with the block dim innermost
+(arbitrary); slots are parallel.  A K/V tile spans the full ``(KH, D)``
+minor dims (tiling-legal for every head count) and the program loops
+over the KV heads inside.  The GQA group of G = H/KH query heads rides
+as rows of the q/out tiles.
 """
 from __future__ import annotations
 
@@ -43,53 +45,114 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.lowrank_matmul import CompilerParams
-
-_NEG_INF = -1e30
-_MINOR = 128        # f32 scratch lane width for the (G, 1) running stats
+from repro.kernels import decode_attention_q as dak
+from repro.kernels import tpu
+from repro.kernels.decode_attention_q import (_MINOR, block_logits,
+                                              init_scratch,
+                                              online_softmax_step)
 
 
 def _kernel(bt_ref, cp_ref, q_ref, k_ref, v_ref, o_ref,
             acc_ref, m_ref, l_ref, *, scale, softcap):
-    """q (1,1,G,D); k/v (1,bs,1,D) — the physical block the index map
+    """q (1,KH,G,D); k/v (1,bs,KH,D) — the physical block the index map
     aimed at; bt (B,nblk) / cache_pos (B,1) i32 SMEM (scalar prefetch);
-    o (1,1,G,D); scratch acc (G,D), m/l (G,128) f32 (col 0 live)."""
+    o (1,KH,G,D); scratch acc (KH,G,D), m/l (KH,G,128) f32."""
     b = pl.program_id(0)
-    si = pl.program_id(2)
-    ns = pl.num_programs(2)
-    bs = k_ref.shape[1]
+    si = pl.program_id(1)
+    ns = pl.num_programs(1)
+    bs, kh = k_ref.shape[1], k_ref.shape[2]
 
     @pl.when(si == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        init_scratch(acc_ref, m_ref, l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                     # (G, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)               # (bs, D)
-    s = jnp.dot(q * scale, k.T,
-                preferred_element_type=jnp.float32)         # (G, bs)
-    if softcap:
-        s = jnp.tanh(s / softcap) * softcap
     pos = si * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    s = jnp.where(pos <= cp_ref[b, 0], s, _NEG_INF)
-
-    m_prev = m_ref[:, :1]                                   # (G, 1)
-    l_prev = l_ref[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                                  # (G, bs)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)               # (bs, D)
-    acc = acc_ref[...] * alpha + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    limit = cp_ref[b, 0]
+    for h in range(kh):
+        s = block_logits(q_ref[0, h].astype(jnp.float32),
+                         k_ref[0, :, h, :].astype(jnp.float32), None,
+                         scale, softcap, pos, limit)
+        online_softmax_step(s, v_ref[0, :, h, :].astype(jnp.float32), h,
+                            acc_ref, m_ref, l_ref)
 
     @pl.when(si == ns - 1)
     def _flush():
-        o_ref[0, 0] = (acc / l_new).astype(o_ref.dtype)
+        for h in range(kh):
+            o_ref[0, h] = (acc_ref[h] / l_ref[h][:, :1]).astype(o_ref.dtype)
+
+
+def _kernel_q(bt_ref, cp_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, o_ref,
+              acc_ref, m_ref, l_ref, *, scale, softcap):
+    """Int8 twin: k_q/v_q (1,bs,KH,D) int8 + PER-BLOCK k/v_scale (1,KH,D)
+    f32 tiles follow the same block-table index maps.  K scales fold
+    into the query row per block; V scales multiply each block's
+    context contribution before accumulation."""
+    b = pl.program_id(0)
+    si = pl.program_id(1)
+    ns = pl.num_programs(1)
+    bs, kh = kq_ref.shape[1], kq_ref.shape[2]
+
+    @pl.when(si == 0)
+    def _init():
+        init_scratch(acc_ref, m_ref, l_ref)
+
+    pos = si * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+    limit = cp_ref[b, 0]
+    for h in range(kh):
+        s = block_logits(q_ref[0, h].astype(jnp.float32),
+                         kq_ref[0, :, h, :].astype(jnp.float32),
+                         ks_ref[0, h].astype(jnp.float32), scale,
+                         softcap, pos, limit)
+        online_softmax_step(s, vq_ref[0, :, h, :].astype(jnp.float32), h,
+                            acc_ref, m_ref, l_ref,
+                            v_scale=vs_ref[0, h].astype(jnp.float32))
+
+    @pl.when(si == ns - 1)
+    def _flush():
+        for h in range(kh):
+            o_ref[0, h] = (acc_ref[h] / l_ref[h][:, :1]).astype(o_ref.dtype)
+
+
+def _call(kernel, q, kv, block_tables, cache_pos, *, softcap, interpret,
+          scales=None):
+    """Shared launch of both paged kernels.  ``kv`` = (k, v) tiles,
+    ``scales`` = per-block (k_scale, v_scale) for the int8 pool."""
+    b, kh, g, d = q.shape
+    k, v = kv
+    _, bs, kh2, d2 = k.shape
+    assert (kh, d) == (kh2, d2), (q.shape, k.shape)
+    assert k.shape == v.shape
+    nblk = block_tables.shape[1]
+    assert block_tables.shape == (b, nblk), block_tables.shape
+    assert cache_pos.shape == (b, 1), cache_pos.shape
+
+    qo = pl.BlockSpec((1, kh, g, d), lambda i, s, bt, cp: (i, 0, 0, 0))
+    kvs = pl.BlockSpec((1, bs, kh, d),
+                       lambda i, s, bt, cp: (bt[i, s], 0, 0, 0))
+    if scales is None:
+        in_specs, operands = [qo, kvs, kvs], (q, k, v)
+    else:
+        ks, vs = scales
+        assert ks.shape == vs.shape == (k.shape[0], kh, d), \
+            (ks.shape, vs.shape)
+        sc = pl.BlockSpec((1, kh, d), lambda i, s, bt, cp: (bt[i, s], 0, 0))
+        in_specs, operands = [qo, kvs, sc, kvs, sc], (q, k, ks, v, vs)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, nblk),
+        in_specs=in_specs,
+        out_specs=qo,
+        scratch_shapes=[pltpu.VMEM((kh, g, d), jnp.float32),
+                        pltpu.VMEM((kh, g, _MINOR), jnp.float32),
+                        pltpu.VMEM((kh, g, _MINOR), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(kernel, scale=1.0 / (d ** 0.5), softcap=softcap),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
+        interpret=interpret,
+        compiler_params=tpu.compiler_params("parallel", "arbitrary"),
+    )(block_tables, cache_pos, *operands)
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
@@ -105,87 +168,8 @@ def decode_attention_paged(q: jax.Array, k: jax.Array, v: jax.Array,
     block size IS the pool's block size (no padding: nblk covers
     exactly blocks_per_slot logical blocks).
     """
-    b, kh, g, d = q.shape
-    nb1, bs, kh2, d2 = k.shape
-    assert (kh, d) == (kh2, d2), (q.shape, k.shape)
-    assert k.shape == v.shape
-    nblk = block_tables.shape[1]
-    assert block_tables.shape == (b, nblk), block_tables.shape
-    assert cache_pos.shape == (b, 1), cache_pos.shape
-
-    grid = (b, kh, nblk)
-    kernel = functools.partial(_kernel, scale=1.0 / (d ** 0.5),
-                               softcap=softcap)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda i, j, s, bt, cp: (i, j, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda i, j, s, bt, cp: (bt[i, s], 0, j, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda i, j, s, bt, cp: (bt[i, s], 0, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda i, j, s, bt, cp: (i, j, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((g, d), jnp.float32),
-                        pltpu.VMEM((g, _MINOR), jnp.float32),
-                        pltpu.VMEM((g, _MINOR), jnp.float32)],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
-        interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(block_tables, cache_pos, q, k, v)
-
-
-def _kernel_q(bt_ref, cp_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, o_ref,
-              acc_ref, m_ref, l_ref, *, scale, softcap):
-    """Int8 twin: k_q/v_q (1,bs,1,D) int8 + PER-BLOCK k/v_scale (1,1,D)
-    f32 tiles follow the same block-table index maps.  K scales fold
-    into the query row per block; V scales multiply each block's
-    context contribution before accumulation."""
-    b = pl.program_id(0)
-    si = pl.program_id(2)
-    ns = pl.num_programs(2)
-    bs = kq_ref.shape[1]
-
-    @pl.when(si == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)                     # (G, D)
-    ks = ks_ref[0, 0].astype(jnp.float32)                   # (D,)
-    kq = kq_ref[0, :, 0, :].astype(jnp.float32)             # (bs, D)
-    s = jnp.dot(q * (ks * scale)[None, :], kq.T,
-                preferred_element_type=jnp.float32)         # (G, bs)
-    if softcap:
-        s = jnp.tanh(s / softcap) * softcap
-    pos = si * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    s = jnp.where(pos <= cp_ref[b, 0], s, _NEG_INF)
-
-    m_prev = m_ref[:, :1]                                   # (G, 1)
-    l_prev = l_ref[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                                  # (G, bs)
-    vq = vq_ref[0, :, 0, :].astype(jnp.float32)             # (bs, D)
-    vs = vs_ref[0, 0].astype(jnp.float32)                   # (D,)
-    acc = acc_ref[...] * alpha + jnp.dot(
-        p, vq, preferred_element_type=jnp.float32) * vs[None, :]
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(si == ns - 1)
-    def _flush():
-        o_ref[0, 0] = (acc / l_new).astype(o_ref.dtype)
+    return _call(_kernel, q, (k, v), block_tables, cache_pos,
+                 softcap=softcap, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
@@ -200,57 +184,15 @@ def decode_attention_paged_q(q: jax.Array, k_q: jax.Array,
     k/v_scale (NB+1, KH, D) f32; block_tables (B, nblk) int32;
     cache_pos (B, 1) int32 -> (B, KH, G, D) in q.dtype.
     """
-    b, kh, g, d = q.shape
-    nb1, bs, kh2, d2 = k_q.shape
-    assert (kh, d) == (kh2, d2), (q.shape, k_q.shape)
-    assert k_q.shape == v_q.shape
-    assert k_scale.shape == v_scale.shape == (nb1, kh, d), \
-        (k_scale.shape, v_scale.shape)
-    nblk = block_tables.shape[1]
-    assert block_tables.shape == (b, nblk), block_tables.shape
-    assert cache_pos.shape == (b, 1), cache_pos.shape
-
-    grid = (b, kh, nblk)
-    kernel = functools.partial(_kernel_q, scale=1.0 / (d ** 0.5),
-                               softcap=softcap)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda i, j, s, bt, cp: (i, j, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda i, j, s, bt, cp: (bt[i, s], 0, j, 0)),
-            pl.BlockSpec((1, 1, d),
-                         lambda i, j, s, bt, cp: (bt[i, s], j, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda i, j, s, bt, cp: (bt[i, s], 0, j, 0)),
-            pl.BlockSpec((1, 1, d),
-                         lambda i, j, s, bt, cp: (bt[i, s], j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda i, j, s, bt, cp: (i, j, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((g, d), jnp.float32),
-                        pltpu.VMEM((g, _MINOR), jnp.float32),
-                        pltpu.VMEM((g, _MINOR), jnp.float32)],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
-        interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(block_tables, cache_pos, q, k_q, k_scale, v_q, v_scale)
+    return _call(_kernel_q, q, (k_q, v_q), block_tables, cache_pos,
+                 softcap=softcap, interpret=interpret,
+                 scales=(k_scale, v_scale))
 
 
-def vmem_bytes(g: int, d: int, block_size: int, act_bytes: int = 4,
-               q_bytes: int = 1) -> int:
+def vmem_bytes(kh: int, g: int, d: int, block_size: int,
+               act_bytes: int = 4, q_bytes: int = 1) -> int:
     """VMEM footprint of one grid step (fit check used by ops.py) —
     same tile inventory as the slot kernel; the scale rows are absent
-    from the f32 variant but cost nothing to keep in the bound."""
-    return (g * d * act_bytes                 # q tile
-            + 2 * block_size * d * q_bytes    # k + v block tiles
-            + 2 * d * 4                       # per-block k/v scale rows
-            + g * d * act_bytes               # out tile
-            + g * d * 4                       # f32 accumulator
-            + 2 * g * _MINOR * 4)             # running max / sum
+    from the full-width variant but cost nothing to keep in the bound."""
+    return dak.vmem_bytes(kh, g, d, block_size, act_bytes=act_bytes,
+                          q_bytes=q_bytes)

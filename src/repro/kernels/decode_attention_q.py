@@ -9,7 +9,8 @@ fallback materializes a full-precision pool copy in HBM every step and
 hands the win straight back.  This kernel keeps the narrow bytes all
 the way into VMEM:
 
-* int8 K/V tiles stream in per ``(slot, kv_head)`` program;
+* int8 K/V tiles stream in per ``(slot, sequence block)`` program,
+  all KV heads at once;
 * per-(slot, head, channel) scales (:mod:`repro.quant.kv` layout) fold
   into the *query* row for K (``(q * k_scale) @ k_q^T == q @ dq(k)^T``)
   and into the final output for V (``(p @ v_q) * v_scale``) — O(D)
@@ -21,8 +22,10 @@ the way into VMEM:
   live iff ``p <= cache_pos[slot]`` — the slot's freshly written token
   included), which also neutralizes the S padding ``ops.py`` adds.
 
-Grid: ``(B, KV_heads, S/bs)`` with the sequence dim innermost
-(arbitrary); slots and heads are parallel.  The GQA group of G = H/KH
+Grid: ``(B, S/bs)`` with the sequence dim innermost (arbitrary); slots
+are parallel.  A K/V block spans the full ``(KH, D)`` minor dims, the
+only block shape the TPU tiling accepts for every head count, and the
+program loops over the KV heads inside.  The GQA group of G = H/KH
 query heads rides along as rows of the q/out tiles, so one pass over a
 K/V tile serves the whole group.
 
@@ -45,57 +48,84 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.lowrank_matmul import CompilerParams
+from repro.kernels import tpu
 
 DEFAULT_BS = 128
 _NEG_INF = -1e30
 _MINOR = 128        # f32 scratch lane width for the (G, 1) running stats
 
 
-def _kernel(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, cp_ref, o_ref,
-            acc_ref, m_ref, l_ref, *, scale, softcap):
-    """q (1,1,G,D); k_q/v_q (1,bs,1,D) int8; k/v_scale (1,1,D) f32;
-    cache_pos (1,1) i32 SMEM; o (1,1,G,D); scratch acc (G,D),
-    m/l (G,128) f32 (col 0 live, broadcast across lanes)."""
-    si = pl.program_id(2)
-    ns = pl.num_programs(2)
-    bs = kq_ref.shape[1]
-
-    @pl.when(si == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)                     # (G, D)
-    ks = ks_ref[0, 0].astype(jnp.float32)                   # (D,)
-    kq = kq_ref[0, :, 0, :].astype(jnp.float32)             # (bs, D)
-    # K scales + 1/sqrt(D) fold into the single query row.
-    s = jnp.dot(q * (ks * scale)[None, :], kq.T,
-                preferred_element_type=jnp.float32)         # (G, bs)
-    if softcap:
-        s = jnp.tanh(s / softcap) * softcap
-    pos = si * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    s = jnp.where(pos <= cp_ref[0, 0], s, _NEG_INF)
-
-    m_prev = m_ref[:, :1]                                   # (G, 1)
-    l_prev = l_ref[:, :1]
+def online_softmax_step(s, v, h, acc_ref, m_ref, l_ref, v_scale=None):
+    """Fold one sequence block of KV head ``h`` into the running
+    softmax: ``s`` (G, bs) masked f32 logits, ``v`` (bs, D) values,
+    optional per-channel ``v_scale`` (D,) applied to this block's
+    context.  Scratch acc (KH, G, D), m/l (KH, G, 128) f32 (col 0
+    live, broadcast across lanes)."""
+    m_prev = m_ref[h][:, :1]                                # (G, 1)
+    l_prev = l_ref[h][:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)                                  # (G, bs)
-    vq = vq_ref[0, :, 0, :].astype(jnp.float32)             # (bs, D)
-    acc = acc_ref[...] * alpha + jnp.dot(
-        p, vq, preferred_element_type=jnp.float32)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    pv = jnp.dot(p, v, preferred_element_type=jnp.float32)  # (G, D)
+    if v_scale is not None:
+        pv = pv * v_scale[None, :]
+    acc_ref[h] = acc_ref[h] * alpha + pv
+    m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+    l_ref[h] = jnp.broadcast_to(l_prev * alpha
+                                + jnp.sum(p, axis=-1, keepdims=True),
+                                l_ref.shape[1:])
+
+
+def init_scratch(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def block_logits(q, k, k_scale, scale, softcap, pos, limit):
+    """Masked (G, bs) f32 logits of one KV head over one block: the
+    K scales (when quantized) and 1/sqrt(D) fold into the query rows;
+    position ``p`` is live iff ``p <= limit``."""
+    qs = q * (scale if k_scale is None else (k_scale * scale)[None, :])
+    s = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if softcap:
+        s = jnp.tanh(s / softcap) * softcap
+    return jnp.where(pos <= limit, s, _NEG_INF)
+
+
+def _kernel(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, cp_ref, o_ref,
+            acc_ref, m_ref, l_ref, *, scale, softcap):
+    """q (1,KH,G,D); k_q/v_q (1,bs,KH,D) int8; k/v_scale (1,KH,D) f32;
+    cache_pos (B,1) i32 SMEM; o (1,KH,G,D); scratch acc (KH,G,D),
+    m/l (KH,G,128) f32.  The KV heads loop inside the program, so the
+    K/V block spans the full (KH, D) minor dims (tiling-legal for any
+    head count)."""
+    si = pl.program_id(1)
+    ns = pl.num_programs(1)
+    bs, kh = kq_ref.shape[1], kq_ref.shape[2]
+
+    @pl.when(si == 0)
+    def _init():
+        init_scratch(acc_ref, m_ref, l_ref)
+
+    pos = si * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+    limit = cp_ref[pl.program_id(0), 0]
+    for h in range(kh):
+        s = block_logits(q_ref[0, h].astype(jnp.float32),
+                         kq_ref[0, :, h, :].astype(jnp.float32),
+                         ks_ref[0, h].astype(jnp.float32), scale,
+                         softcap, pos, limit)
+        online_softmax_step(s, vq_ref[0, :, h, :].astype(jnp.float32), h,
+                            acc_ref, m_ref, l_ref)
 
     @pl.when(si == ns - 1)
     def _flush():
-        vs = vs_ref[0, 0].astype(jnp.float32)               # (D,)
-        o = acc / l_new * vs[None, :]   # V scales fold into the output
-        o_ref[0, 0] = o.astype(o_ref.dtype)
+        for h in range(kh):
+            vs = vs_ref[0, h].astype(jnp.float32)           # (D,)
+            # V scales fold into the output
+            o = acc_ref[h] / l_ref[h][:, :1] * vs[None, :]
+            o_ref[0, h] = o.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -120,41 +150,40 @@ def decode_attention_q(q: jax.Array, k_q: jax.Array, k_scale: jax.Array,
     assert cache_pos.shape == (b, 1), cache_pos.shape
     assert s % bs == 0, (s, bs)
 
-    grid = (b, kh, s // bs)
+    grid = (b, s // bs)
     kernel = functools.partial(_kernel, scale=1.0 / (d ** 0.5),
                                softcap=softcap)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda i, j, k: (i, j, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d), lambda i, j, k: (i, k, j, 0)),
-            pl.BlockSpec((1, 1, d), lambda i, j, k: (i, j, 0)),
-            pl.BlockSpec((1, bs, 1, d), lambda i, j, k: (i, k, j, 0)),
-            pl.BlockSpec((1, 1, d), lambda i, j, k: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, kh, g, d), lambda i, k: (i, 0, 0, 0)),
+            pl.BlockSpec((1, bs, kh, d), lambda i, k: (i, k, 0, 0)),
+            pl.BlockSpec((1, kh, d), lambda i, k: (i, 0, 0)),
+            pl.BlockSpec((1, bs, kh, d), lambda i, k: (i, k, 0, 0)),
+            pl.BlockSpec((1, kh, d), lambda i, k: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # whole (B, 1)
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda i, j, k: (i, j, 0, 0)),
+        out_specs=pl.BlockSpec((1, kh, g, d), lambda i, k: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((g, d), jnp.float32),
-                        pltpu.VMEM((g, _MINOR), jnp.float32),
-                        pltpu.VMEM((g, _MINOR), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((kh, g, d), jnp.float32),
+                        pltpu.VMEM((kh, g, _MINOR), jnp.float32),
+                        pltpu.VMEM((kh, g, _MINOR), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=tpu.compiler_params("parallel", "arbitrary"),
     )(q, k_q, k_scale, v_q, v_scale, cache_pos)
 
 
-def vmem_bytes(g: int, d: int, s_block: int, act_bytes: int = 4,
+def vmem_bytes(kh: int, g: int, d: int, s_block: int, act_bytes: int = 4,
                q_bytes: int = 1) -> int:
     """VMEM footprint of one grid step (fit check used by ops.py)."""
-    return (g * d * act_bytes                 # q tile
-            + 2 * s_block * d * q_bytes       # k_q + v_q tiles
-            + 2 * d * 4                       # k/v scale rows
-            + g * d * act_bytes               # out tile
-            + g * d * 4                       # f32 accumulator
-            + 2 * g * _MINOR * 4)             # running max / sum
+    blocks = (kh * g * d * act_bytes          # q tile
+              + 2 * s_block * kh * d * q_bytes   # k_q + v_q tiles
+              + 2 * kh * d * 4                # k/v scale rows
+              + kh * g * d * act_bytes)       # out tile
+    return (tpu.BUFFERS * blocks
+            + kh * g * d * 4                  # f32 accumulator
+            + 2 * kh * g * _MINOR * 4)        # running max / sum
 
 
 # ---------------------------------------------------------------------------
@@ -164,7 +193,7 @@ def vmem_bytes(g: int, d: int, s_block: int, act_bytes: int = 4,
 def _latent_kernel(ql_ref, qr_ref, cq_ref, cs_ref, rq_ref, rs_ref, cp_ref,
                    o_ref, acc_ref, m_ref, l_ref, *, scale):
     """q_lat (1,H,L); q_rope (1,H,R); ckv_q (1,bs,L) / krope_q (1,bs,R)
-    int8; ckv/krope_scale (1,L)/(1,R) f32; cache_pos (1,1) i32 SMEM;
+    int8; ckv/krope_scale (1,1,L)/(1,1,R) f32; cache_pos (B,1) i32 SMEM;
     o (1,H,L); scratch acc (H,L), m/l (H,128) f32 (col 0 live)."""
     si = pl.program_id(1)
     ns = pl.num_programs(1)
@@ -178,8 +207,8 @@ def _latent_kernel(ql_ref, qr_ref, cq_ref, cs_ref, rq_ref, rs_ref, cp_ref,
 
     ql = ql_ref[0].astype(jnp.float32)                      # (H, L)
     qr = qr_ref[0].astype(jnp.float32)                      # (H, R)
-    cs = cs_ref[0].astype(jnp.float32)                      # (L,)
-    rs = rs_ref[0].astype(jnp.float32)                      # (R,)
+    cs = cs_ref[0, 0].astype(jnp.float32)                   # (L,)
+    rs = rs_ref[0, 0].astype(jnp.float32)                   # (R,)
     cq = cq_ref[0].astype(jnp.float32)                      # (bs, L)
     rq = rq_ref[0].astype(jnp.float32)                      # (bs, R)
     # Latent + rope scales (and the 1/sqrt(nope+rope) logit scale) fold
@@ -189,7 +218,7 @@ def _latent_kernel(ql_ref, qr_ref, cq_ref, cs_ref, rq_ref, rs_ref, cp_ref,
          + jnp.dot(qr * (rs * scale)[None, :], rq.T,
                    preferred_element_type=jnp.float32))     # (H, bs)
     pos = si * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    s = jnp.where(pos <= cp_ref[0, 0], s, _NEG_INF)
+    s = jnp.where(pos <= cp_ref[pl.program_id(0), 0], s, _NEG_INF)
 
     m_prev = m_ref[:, :1]                                   # (H, 1)
     l_prev = l_ref[:, :1]
@@ -238,6 +267,8 @@ def decode_attention_latent_q(q_lat: jax.Array, q_rope: jax.Array,
 
     grid = (b, s // bs)
     kernel = functools.partial(_latent_kernel, scale=scale)
+    # Scale rows go in as (B, 1, C): a (1, 1, C) block is tiling-legal
+    # for any B, a (1, C) block over (B, C) only for B == 1.
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -245,11 +276,10 @@ def decode_attention_latent_q(q_lat: jax.Array, q_rope: jax.Array,
             pl.BlockSpec((1, h, lora), lambda i, k: (i, 0, 0)),
             pl.BlockSpec((1, h, rope), lambda i, k: (i, 0, 0)),
             pl.BlockSpec((1, bs, lora), lambda i, k: (i, k, 0)),
-            pl.BlockSpec((1, lora), lambda i, k: (i, 0)),
+            pl.BlockSpec((1, 1, lora), lambda i, k: (i, 0, 0)),
             pl.BlockSpec((1, bs, rope), lambda i, k: (i, k, 0)),
-            pl.BlockSpec((1, rope), lambda i, k: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, k: (i, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, rope), lambda i, k: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # whole (B, 1)
         ],
         out_specs=pl.BlockSpec((1, h, lora), lambda i, k: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, lora), q_lat.dtype),
@@ -257,17 +287,18 @@ def decode_attention_latent_q(q_lat: jax.Array, q_rope: jax.Array,
                         pltpu.VMEM((h, _MINOR), jnp.float32),
                         pltpu.VMEM((h, _MINOR), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-    )(q_lat, q_rope, ckv_q, ckv_scale, krope_q, krope_scale, cache_pos)
+        compiler_params=tpu.compiler_params("parallel", "arbitrary"),
+    )(q_lat, q_rope, ckv_q, ckv_scale[:, None], krope_q,
+      krope_scale[:, None], cache_pos)
 
 
 def vmem_bytes_latent(h: int, lora: int, rope: int, s_block: int,
                       act_bytes: int = 4, q_bytes: int = 1) -> int:
     """VMEM footprint of one latent grid step (fit check for ops.py)."""
-    return (h * (lora + rope) * act_bytes     # q_lat + q_rope tiles
-            + s_block * (lora + rope) * q_bytes   # ckv_q + krope_q tiles
-            + (lora + rope) * 4               # scale rows
-            + h * lora * act_bytes            # out tile
+    blocks = (h * (lora + rope) * act_bytes   # q_lat + q_rope tiles
+              + s_block * (lora + rope) * q_bytes   # ckv_q + krope_q
+              + (lora + rope) * 4             # scale rows
+              + h * lora * act_bytes)         # out tile
+    return (tpu.BUFFERS * blocks
             + h * lora * 4                    # f32 accumulator
             + 2 * h * _MINOR * 4)             # running max / sum

@@ -25,10 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
+from repro.kernels import tpu
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -77,16 +74,15 @@ def lowrank_matmul(x: jax.Array, w0: jax.Array, w1: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((m, s), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, r), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=tpu.compiler_params("parallel", "arbitrary"),
     )(x, w0, w1)
 
 
 def vmem_bytes(m_block: int, c: int, r: int, s_block: int,
                dtype_bytes: int = 2) -> int:
     """VMEM footprint of one grid step (fit check used by ops.py)."""
-    return (m_block * c * dtype_bytes          # x block
-            + c * r * dtype_bytes              # w0 (resident)
-            + r * s_block * dtype_bytes        # w1 block
-            + m_block * s_block * dtype_bytes  # out block
-            + m_block * r * 4)                 # f32 scratch h
+    blocks = (m_block * c * dtype_bytes        # x block
+              + c * r * dtype_bytes            # w0 (resident)
+              + r * s_block * dtype_bytes      # w1 block
+              + m_block * s_block * dtype_bytes)  # out block
+    return tpu.BUFFERS * blocks + m_block * r * 4   # + f32 scratch h

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.lowrank_matmul import CompilerParams
+from repro.kernels import tpu
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -86,18 +86,19 @@ def lowrank_matmul_q(x: jax.Array, w0_q: jax.Array, w0_scale: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, s), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, r), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=tpu.compiler_params("parallel", "arbitrary"),
     )(x, w0_q, w0_scale, w1_q, w1_scale)
 
 
 def vmem_bytes(m_block: int, c: int, r: int, s_block: int,
                act_bytes: int = 2, q_bytes: int = 1) -> int:
     """VMEM footprint of one grid step (fit check used by ops.py)."""
-    return (m_block * c * act_bytes           # x block
-            + c * r * q_bytes                 # w0_q (resident)
-            + r * 4                           # w0_scale
-            + r * s_block * q_bytes           # w1_q block
-            + s_block * 4                     # w1_scale block
-            + m_block * s_block * act_bytes   # out block
-            + m_block * r * 4)                # f32 scratch h
+    blocks = (m_block * c * act_bytes         # x block
+              + c * r * q_bytes               # w0_q (resident)
+              + r * 4                         # w0_scale
+              + r * s_block * q_bytes         # w1_q block
+              + s_block * 4                   # w1_scale block
+              + m_block * s_block * act_bytes)  # out block
+    return (tpu.BUFFERS * blocks
+            + m_block * r * 4                 # f32 scratch h
+            + r * s_block * (4 + act_bytes))  # dequantized w1 tile

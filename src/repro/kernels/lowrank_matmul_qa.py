@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.lowrank_matmul import CompilerParams
+from repro.kernels import tpu
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -113,8 +113,7 @@ def lowrank_matmul_qa(x: jax.Array, w0_q: jax.Array, w0_scale: jax.Array,
         scratch_shapes=[pltpu.VMEM((bm, r), jnp.int8),
                         pltpu.VMEM((bm, 1), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=tpu.compiler_params("parallel", "arbitrary"),
     )(x, w0_q, w0_scale, w1_q, w1_scale)
 
 
@@ -126,14 +125,15 @@ def vmem_bytes(m_block: int, c: int, r: int, s_block: int,
     copy and row scales, the int8 factor tiles + scale rows, the int8
     rank scratch (+ f32 transient h at requant), and the out block.
     """
-    return (m_block * c * act_bytes           # x block
+    blocks = (m_block * c * act_bytes         # x block
+              + c * r * q_bytes               # w0_q (resident)
+              + r * 4                         # w0_scale
+              + r * s_block * q_bytes         # w1_q block
+              + s_block * 4                   # w1_scale block
+              + m_block * s_block * act_bytes)  # out block
+    return (tpu.BUFFERS * blocks
             + m_block * c                     # int8 x (transient)
             + m_block * 4                     # x row scales
-            + c * r * q_bytes                 # w0_q (resident)
-            + r * 4                           # w0_scale
-            + r * s_block * q_bytes           # w1_q block
-            + s_block * 4                     # w1_scale block
-            + m_block * s_block * act_bytes   # out block
             + m_block * r                     # int8 scratch h
             + m_block * r * 4                 # f32 h at requant (transient)
             + m_block * 4)                    # h row scales

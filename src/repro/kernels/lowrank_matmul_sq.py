@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.lowrank_matmul import CompilerParams
+from repro.kernels import tpu
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -121,8 +121,7 @@ def lowrank_matmul_sq(x: jax.Array, w0_sp: jax.Array, w0_idx: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, s), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, r), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=tpu.compiler_params("parallel", "arbitrary"),
     )(x, w0_sp, w0_idx, w0_scale, w1_sp, w1_idx, w1_scale)
 
 
@@ -136,9 +135,9 @@ def vmem_bytes(m_block: int, c: int, r: int, s_block: int,
     packed = (c // 2) * r + (r // 2) * s_block       # kept values
     meta = (c // 2) + (r // 2)                       # int8 indices
     expanded = (c * r + r * s_block) * (4 + act_bytes)
-    return (m_block * c * act_bytes                  # x block
-            + packed * q_bytes + meta
-            + (r + s_block) * 4                      # f32 scales
-            + expanded
-            + m_block * s_block * act_bytes          # out block
+    blocks = (m_block * c * act_bytes                # x block
+              + packed * q_bytes + meta
+              + (r + s_block) * 4                    # f32 scales
+              + m_block * s_block * act_bytes)       # out block
+    return (tpu.BUFFERS * blocks + expanded
             + m_block * r * 4)                       # f32 scratch h

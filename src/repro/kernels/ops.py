@@ -3,7 +3,8 @@
 Handles:
 * leading-batch flattening (``(..., C) -> (M, C)``),
 * padding M/S up to tile multiples (and slicing back),
-* interpret-mode on CPU (the container target) vs compiled on TPU,
+* interpret mode on the CPU backend, compiled everywhere else
+  (:func:`repro.kernels.tpu.interpret`),
 * VMEM-fit dispatch — :func:`kernel_fits` is the single fit predicate;
   :class:`repro.layers.plan.LinearPlan` consults it for its kernel
   eligibility decision and the wrappers use it as the fallback check for
@@ -27,9 +28,11 @@ from repro.kernels import lowrank_matmul_q as qk
 from repro.kernels import lowrank_matmul_qa as aqk
 from repro.kernels import lowrank_matmul_sq as sk
 from repro.kernels import ref
+from repro.kernels import tpu
 
-# v5e practical per-core VMEM working-set budget (conservative).
-VMEM_BUDGET = 64 * 1024 * 1024
+#: the budget :func:`kernel_fits` checks against — the same number every
+#: kernel passes to the compiler as ``vmem_limit_bytes``
+VMEM_BUDGET = tpu.VMEM_LIMIT_BYTES
 
 #: serve-tier fault injection hook (``kernel_gate`` point): when set,
 #: :func:`kernel_fits` consults it and a fire forces the jnp reference
@@ -47,10 +50,6 @@ def set_fault_injector(inj) -> None:
     _FAULT_INJECTOR = inj
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _pad_to(x: jax.Array, axis: int, mult: int) -> tuple[jax.Array, int]:
     size = x.shape[axis]
     pad = (-size) % mult
@@ -65,18 +64,25 @@ def _bm_eff(bm: int, m: int) -> int:
     return min(bm, max(8, m))
 
 
-def kernel_fits(kernel: str, m: int, *, c: int, s: int, r: int = 0,
-                r1: int = 0, r2: int = 0, q_bytes: int = 1,
-                bm: int | None = None, bn: int | None = None) -> bool:
+def kernel_fits(kernel: str, m: int, **geometry) -> bool:
+    """Does ``kernel`` run at this geometry?  The one predicate behind
+    plan eligibility and the wrappers' fallback dispatch: the VMEM fit
+    (:func:`vmem_fits`), unless the serve tier's fault injector rejects
+    the kernel (the ``kernel_gate`` point)."""
+    if _FAULT_INJECTOR is not None and _FAULT_INJECTOR.fire("kernel_gate"):
+        return False               # injected rejection -> jnp fallback
+    return vmem_fits(kernel, m, **geometry)
+
+
+def vmem_fits(kernel: str, m: int, *, c: int, s: int, r: int = 0,
+              r1: int = 0, r2: int = 0, kh: int = 1, q_bytes: int = 1,
+              bm: int | None = None, bn: int | None = None) -> bool:
     """Does one grid step of ``kernel`` at this geometry fit the VMEM
-    budget?  The one fit predicate behind plan eligibility and the
-    wrappers' fallback dispatch.  ``bm``/``bn`` default to the kernel's
+    budget the compiler is given?  ``bm``/``bn`` default to the kernel's
     own tile sizes; wrappers pass the caller's so the fit check matches
     the launch.  The S-block is the full ``bn`` — the wrappers pad S up
     to a ``bn`` multiple, so the launched block is never narrower."""
     del s  # padded up to a bn multiple at launch
-    if _FAULT_INJECTOR is not None and _FAULT_INJECTOR.fire("kernel_gate"):
-        return False               # injected rejection -> jnp fallback
     if kernel == "lowrank":
         return lk.vmem_bytes(_bm_eff(bm or lk.DEFAULT_BM, m), c, r,
                              bn or lk.DEFAULT_BN) <= VMEM_BUDGET
@@ -108,16 +114,17 @@ def kernel_fits(kernel: str, m: int, *, c: int, s: int, r: int = 0,
                               bn or bsk.DEFAULT_BN,
                               q_bytes=q_bytes) <= VMEM_BUDGET
     if kernel == "decode_attn_q":
-        # Per-(slot, kv-head) program: c = head_dim, r = GQA group size,
-        # bn = the sequence block; m (the slot count) is grid-parallel.
-        return dak.vmem_bytes(max(1, r), c, bn or dak.DEFAULT_BS,
+        # Per-(slot, sequence block) program over all kh KV heads: c =
+        # head_dim, r = GQA group size, bn = the sequence block; m (the
+        # slot count) is grid-parallel.
+        return dak.vmem_bytes(kh, max(1, r), c, bn or dak.DEFAULT_BS,
                               q_bytes=q_bytes) <= VMEM_BUDGET
     if kernel == "decode_attn_paged":
-        # Per-(slot, kv-head) program over one physical block: c =
+        # Per-(slot, physical block) program over all kh KV heads: c =
         # head_dim, r = GQA group size, bn = the pool's block size.
         # Same tile inventory as the slot kernel (the f32 variant skips
         # the scale rows, a rounding error in the bound).
-        return dap.vmem_bytes(max(1, r), c, bn or 16,
+        return dap.vmem_bytes(kh, max(1, r), c, bn or 16,
                               q_bytes=q_bytes) <= VMEM_BUDGET
     if kernel == "decode_latent_q":
         # Per-slot program: c = kv_lora_rank, r = head count, r1 = the
@@ -144,7 +151,7 @@ def lowrank_matmul(x: jax.Array, w0: jax.Array, w1: jax.Array, *,
     x2, pad_m = _pad_to(x2, 0, bm_eff)
     w1p, pad_s = _pad_to(w1, 1, bn)
     y = lk.lowrank_matmul(x2, w0, w1p, bm=bm_eff, bn=min(bn, w1p.shape[1]),
-                          interpret=not _on_tpu())
+                          interpret=tpu.interpret())
     if pad_m:
         y = y[:m]
     if pad_s:
@@ -173,7 +180,7 @@ def lowrank_matmul_q(x: jax.Array, w0_q: jax.Array, w0_scale: jax.Array,
     w1sp, _ = _pad_to(w1_scale, 1, bn)     # zero scales -> zero columns
     y = qk.lowrank_matmul_q(x2, w0_q, w0_scale, w1p, w1sp,
                             bm=bm_eff, bn=min(bn, w1p.shape[1]),
-                            interpret=not _on_tpu())
+                            interpret=tpu.interpret())
     if pad_m:
         y = y[:m]
     if pad_s:
@@ -205,7 +212,7 @@ def lowrank_matmul_qa(x: jax.Array, w0_q: jax.Array, w0_scale: jax.Array,
     w1sp, _ = _pad_to(w1_scale, 1, bn)     # zero scales -> zero columns
     y = aqk.lowrank_matmul_qa(x2, w0_q, w0_scale, w1p, w1sp,
                               bm=bm_eff, bn=min(bn, w1p.shape[1]),
-                              interpret=not _on_tpu())
+                              interpret=tpu.interpret())
     if pad_m:
         y = y[:m]
     if pad_s:
@@ -238,7 +245,7 @@ def lowrank_matmul_sq(x: jax.Array, w0_sp: jax.Array, w0_idx: jax.Array,
     y = sk.lowrank_matmul_sq(x2, w0_sp, w0_idx, w0_scale,
                              w1p, w1_idx, w1sp,
                              bm=bm_eff, bn=min(bn, w1p.shape[2]),
-                             interpret=not _on_tpu())
+                             interpret=tpu.interpret())
     if pad_m:
         y = y[:m]
     if pad_s:
@@ -266,7 +273,7 @@ def branched_matmul(x: jax.Array, u: jax.Array, xc: jax.Array,
     vp, pad_s = _pad_to(v, 2, bn)
     y = bk.branched_matmul(x2, u, xc, vp, bm=bm_eff,
                            bn=min(bn, vp.shape[2]),
-                           interpret=not _on_tpu())
+                           interpret=tpu.interpret())
     if pad_m:
         y = y[:m]
     if pad_s:
@@ -301,7 +308,7 @@ def branched_matmul_q(x: jax.Array, u_q: jax.Array, u_scale: jax.Array,
     vsp, _ = _pad_to(v_scale, 2, bn)       # zero scales -> zero columns
     y = bqk.branched_matmul_q(x2, u_q, u_scale, xc_q, xc_scale, vp, vsp,
                               bm=bm_eff, bn=min(bn, vp.shape[2]),
-                              interpret=not _on_tpu())
+                              interpret=tpu.interpret())
     if pad_m:
         y = y[:m]
     if pad_s:
@@ -337,7 +344,7 @@ def branched_matmul_qa(x: jax.Array, u_q: jax.Array, u_scale: jax.Array,
     vsp, _ = _pad_to(v_scale, 2, bn)       # zero scales -> zero columns
     y = bak.branched_matmul_qa(x2, u_q, u_scale, xc_q, xc_scale, vp, vsp,
                                bm=bm_eff, bn=min(bn, vp.shape[2]),
-                               interpret=not _on_tpu())
+                               interpret=tpu.interpret())
     if pad_m:
         y = y[:m]
     if pad_s:
@@ -375,7 +382,7 @@ def branched_matmul_sq(x: jax.Array, u_sp: jax.Array, u_idx: jax.Array,
     y = bsk.branched_matmul_sq(x2, u_sp, u_idx, u_scale, xc_q, xc_scale,
                                vp, v_idx, vsp, bm=bm_eff,
                                bn=min(bn, vp.shape[3]),
-                               interpret=not _on_tpu())
+                               interpret=tpu.interpret())
     if pad_m:
         y = y[:m]
     if pad_s:
@@ -401,7 +408,7 @@ def decode_attention_q(q: jax.Array, k_q: jax.Array, k_scale: jax.Array,
     g = h // kh
     q_bytes = jnp.dtype(k_q.dtype).itemsize
     if not (force_kernel or kernel_fits("decode_attn_q", b, c=d, s=s, r=g,
-                                        q_bytes=q_bytes, bn=bs)):
+                                        kh=kh, q_bytes=q_bytes, bn=bs)):
         return ref.decode_attention_q_ref(q, k_q, k_scale, v_q, v_scale,
                                           cache_pos, softcap=softcap)
     # Head layout matches the jnp decode path: H rows group as (KH, G).
@@ -412,7 +419,7 @@ def decode_attention_q(q: jax.Array, k_q: jax.Array, k_scale: jax.Array,
         qg, kq_p, k_scale, vq_p, v_scale,
         cache_pos.astype(jnp.int32).reshape(b, 1),
         bs=min(bs, kq_p.shape[1]), softcap=softcap,
-        interpret=not _on_tpu())
+        interpret=tpu.interpret())
     return o.reshape(b, 1, h, d)
 
 
@@ -434,14 +441,15 @@ def decode_attention_paged(q: jax.Array, k: jax.Array, v: jax.Array,
     g = h // kh
     q_bytes = jnp.dtype(k.dtype).itemsize
     if not (force_kernel or kernel_fits("decode_attn_paged", b, c=d, s=bs,
-                                        r=g, q_bytes=q_bytes, bn=bs)):
+                                        r=g, kh=kh, q_bytes=q_bytes,
+                                        bn=bs)):
         return ref.decode_attention_paged_ref(q, k, v, block_tables,
                                               cache_pos, softcap=softcap)
     qg = q[:, 0].reshape(b, kh, g, d)
     o = dap.decode_attention_paged(
         qg, k, v, block_tables.astype(jnp.int32),
         cache_pos.astype(jnp.int32).reshape(b, 1),
-        softcap=softcap, interpret=not _on_tpu())
+        softcap=softcap, interpret=tpu.interpret())
     return o.reshape(b, 1, h, d)
 
 
@@ -463,7 +471,8 @@ def decode_attention_paged_q(q: jax.Array, k_q: jax.Array,
     g = h // kh
     q_bytes = jnp.dtype(k_q.dtype).itemsize
     if not (force_kernel or kernel_fits("decode_attn_paged", b, c=d, s=bs,
-                                        r=g, q_bytes=q_bytes, bn=bs)):
+                                        r=g, kh=kh, q_bytes=q_bytes,
+                                        bn=bs)):
         return ref.decode_attention_paged_q_ref(
             q, k_q, k_scale, v_q, v_scale, block_tables, cache_pos,
             softcap=softcap)
@@ -471,7 +480,7 @@ def decode_attention_paged_q(q: jax.Array, k_q: jax.Array,
     o = dap.decode_attention_paged_q(
         qg, k_q, k_scale, v_q, v_scale, block_tables.astype(jnp.int32),
         cache_pos.astype(jnp.int32).reshape(b, 1),
-        softcap=softcap, interpret=not _on_tpu())
+        softcap=softcap, interpret=tpu.interpret())
     return o.reshape(b, 1, h, d)
 
 
@@ -506,5 +515,5 @@ def decode_attention_latent_q(q_lat: jax.Array, q_rope: jax.Array,
     o = dak.decode_attention_latent_q(
         q_lat[:, 0], q_rope[:, 0], cq_p, ckv_scale, rq_p, krope_scale,
         cache_pos.astype(jnp.int32).reshape(b, 1), scale=scale,
-        bs=min(bs, cq_p.shape[1]), interpret=not _on_tpu())
+        bs=min(bs, cq_p.shape[1]), interpret=tpu.interpret())
     return o[:, None]

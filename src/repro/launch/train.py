@@ -5,19 +5,18 @@
         [--compression 2.0] [--freeze] [--branches N] \
         [--ckpt-dir DIR] [--batch B] [--seq S]
 
-On this CPU container only ``--smoke`` configs are trainable; on a real
-slice the same entry launches the full config onto the production mesh
-(the mesh is chosen by device count at startup).
+The launcher calls ``train()`` without a mesh, so the whole run lives on
+JAX's default device: ``--smoke`` configs fit anywhere, full configs only
+where one device holds their params and optimizer state.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 
-import jax
-
 from repro.configs import registry
 from repro.configs.base import LRDConfig, RunConfig, ShapeConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.data import ByteTextLM, SyntheticImages, SyntheticLM
 from repro.train.fault_tolerance import PreemptionHandler, run_with_restart
 from repro.train.loop import train
@@ -44,6 +43,7 @@ def main() -> None:
     ap.add_argument("--max-restarts", type=int, default=2)
     args = ap.parse_args()
 
+    enable_compile_cache()
     entry = registry.get(args.arch)
     cfg = entry.smoke if args.smoke else entry.full
     lrd = (LRDConfig() if args.lrd == "none" else

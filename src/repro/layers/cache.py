@@ -362,6 +362,35 @@ class CachePlan:
 
     # -- decode attention (the cache-coupled read) --------------------------
 
+    def decode_path(self, num_heads: int, batch: int, seq_len: int,
+                    use_pallas: bool) -> str:
+        """How decode attention over this cache runs: the fused
+        kernel's ``kernel_fits`` name, ``"ref"`` when that kernel does
+        not fit its VMEM budget (the ops wrapper then runs the oracle),
+        or ``"jnp"`` where no kernel serves (``use_pallas`` off, or a
+        full-width slot / MLA pool)."""
+        from repro.kernels import ops as kops
+        if not use_pallas or (not self.quantized and self.paged is None):
+            return "jnp"
+        if self.mla:
+            lora = self.leaf("ckv_q").tail_shape[-1]
+            rope = self.leaf("krope_q").tail_shape[-1]
+            name = "decode_latent_q"
+            fits = kops.vmem_fits(name, batch, c=lora, s=seq_len,
+                                  r=num_heads, r1=rope)
+        else:
+            kh, d = self.leaf("k_q" if self.quantized else "k").tail_shape
+            g = num_heads // kh
+            if self.paged is not None:
+                name, bs = "decode_attn_paged", self.paged.block_size
+                fits = kops.vmem_fits(name, batch, c=d, s=bs, r=g, kh=kh,
+                                      bn=bs)
+            else:
+                name = "decode_attn_q"
+                fits = kops.vmem_fits(name, batch, c=d, s=seq_len, r=g,
+                                      kh=kh)
+        return name if fits else "ref"
+
     def attend_decode(self, q: jax.Array, cache: dict,
                       cache_pos: jax.Array, *, softcap: float = 0.0,
                       use_pallas: bool = False) -> jax.Array:

@@ -329,15 +329,26 @@ class LinearPlan:
         """
         if not use_pallas or len(x_shape) < 2:
             return None
+        from repro.kernels import ops as kops
+        m = int(math.prod(x_shape[:-1]))
+        for name in self.kernel_candidates(act_quantize):
+            if kops.kernel_fits(name, m, **self.fit_geometry(name)):
+                return name
+        return None
+
+    def kernel_candidates(self, act_quantize: bool = False
+                          ) -> tuple[str, ...]:
+        """The fused kernels that can execute this plan's storage
+        layout, most preferred first; empty where only the jnp path can
+        (dense and conv kinds, stacked factors, mixed layouts).  Which
+        one runs is then a VMEM-fit question (:meth:`kernel_for`)."""
         if self.kind not in (KIND_LOWRANK, KIND_BRANCHED):
-            return None
+            return ()
         # Stacked (scan-dim) factors never reach the kernels directly.
         want_ndim = 2 if self.kind == KIND_LOWRANK else 3
         if any(len(f.shape) != want_ndim for f in self.factors):
-            return None
-        from repro.kernels import ops as kops
-        m = int(math.prod(x_shape[:-1]))
-        chain = self.matmul_chain()
+            return ()
+        base = "lowrank" if self.kind == KIND_LOWRANK else "branched"
         if self.sparse:
             # The fused sq kernels want the canonical compound layout:
             # every sparse factor also int8 (sp + idx + scale), and for
@@ -346,49 +357,47 @@ class LinearPlan:
             # (mode="none") or a partial sparse_targets mix — expands
             # through the reference path.
             if self.kind == KIND_LOWRANK:
-                if not all(f.sparsity is not None and f.quantized
-                           for f in self.factors):
-                    return None
-                fits = kops.kernel_fits("lowrank_sq", m, c=chain[0][1],
-                                        r=chain[0][2], s=self.d_out)
-                return "lowrank_sq" if fits else None
-            u, xc, v = (self.factor(n) for n in ("u", "xc", "v"))
-            if not (u.sparsity is not None and u.quantized
-                    and v.sparsity is not None and v.quantized
-                    and xc.quantized and xc.sparsity is None):
-                return None
-            fits = kops.kernel_fits("branched_sq", m, c=chain[0][1],
-                                    r1=chain[0][2], r2=chain[1][2],
-                                    s=self.d_out)
-            return "branched_sq" if fits else None
+                ok = all(f.sparsity is not None and f.quantized
+                         for f in self.factors)
+            else:
+                u, xc, v = (self.factor(n) for n in ("u", "xc", "v"))
+                ok = (u.sparsity is not None and u.quantized
+                      and v.sparsity is not None and v.quantized
+                      and xc.quantized and xc.sparsity is None)
+            return (base + "_sq",) if ok else ()
         # Mixed plain/quantized subtrees (partial quant_targets) take
         # the dequant reference path.
         if self.quantized and not self.fully_quantized:
-            return None
-        q_bytes = (jnp.dtype(self.factors[0].dtype).itemsize
-                   if self.fully_quantized else 1)
+            return ()
+        names = []
         if (act_quantize and self.fully_quantized
                 and all(jnp.dtype(f.dtype) == jnp.int8
                         for f in self.factors)):
-            if self.kind == KIND_LOWRANK:
-                if kops.kernel_fits("lowrank_qa", m, c=chain[0][1],
-                                    r=chain[0][2], s=self.d_out,
-                                    q_bytes=q_bytes):
-                    return "lowrank_qa"
-            elif kops.kernel_fits("branched_qa", m, c=chain[0][1],
-                                  r1=chain[0][2], r2=chain[1][2],
-                                  s=self.d_out, q_bytes=q_bytes):
-                return "branched_qa"
-        if self.kind == KIND_LOWRANK:
-            name = "lowrank_q" if self.fully_quantized else "lowrank"
-            fits = kops.kernel_fits(name, m, c=chain[0][1], r=chain[0][2],
-                                    s=self.d_out, q_bytes=q_bytes)
-        else:
-            name = "branched_q" if self.fully_quantized else "branched"
-            fits = kops.kernel_fits(name, m, c=chain[0][1], r1=chain[0][2],
-                                    r2=chain[1][2], s=self.d_out,
-                                    q_bytes=q_bytes)
-        return name if fits else None
+            names.append(base + "_qa")
+        names.append(base + ("_q" if self.fully_quantized else ""))
+        return tuple(names)
+
+    def fit_geometry(self, name: str) -> dict:
+        """``kernel_fits`` keyword geometry of kernel ``name`` for this
+        plan (M aside)."""
+        chain = self.matmul_chain()
+        q_bytes = (jnp.dtype(self.factors[0].dtype).itemsize
+                   if self.fully_quantized else 1)
+        if name.startswith("lowrank"):
+            return dict(c=chain[0][1], r=chain[0][2], s=self.d_out,
+                        q_bytes=q_bytes)
+        return dict(c=chain[0][1], r1=chain[0][2], r2=chain[1][2],
+                    s=self.d_out, q_bytes=q_bytes)
+
+    def unstacked(self) -> "LinearPlan":
+        """The plan one step of a layer scan executes: leading stack
+        (layers / experts) dims dropped from every factor."""
+        if self.kind in CONV_KINDS:
+            return self
+        nd = 2 if self.kind in (KIND_DENSE, KIND_LOWRANK) else 3
+        return dataclasses.replace(self, factors=tuple(
+            dataclasses.replace(f, shape=f.shape[-nd:])
+            for f in self.factors))
 
     # -- execution ----------------------------------------------------------
 
@@ -556,3 +565,30 @@ def tree_summary(plan_tree: PyTree) -> dict:
         "weight_bytes": sum(p.weight_bytes for p in plans),
         "quant_bytes": sum(p.quant_bytes for p in plans),
     }
+
+
+def kernel_census(plan_tree: PyTree, m: int, *, use_pallas: bool,
+                  act_quantize: bool = False) -> dict:
+    """How every linear of a ``build_plan_tree`` result executes for an
+    activation of ``m`` rows: a count per fused kernel, ``"ref"`` for
+    plans a kernel serves but whose geometry does not fit its VMEM
+    budget (the jnp reference runs instead), and ``"jnp"`` for plans
+    no kernel serves (dense kinds, or ``use_pallas`` off).  Stacked
+    plans count once per scan step, at the per-step geometry; MoE
+    expert banks (under an ``experts`` key) run as grouped einsums."""
+    from repro.kernels import ops as kops
+    out: dict[str, int] = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            plan_tree, is_leaf=lambda n: isinstance(n, LinearPlan))[0]:
+        if not isinstance(leaf, LinearPlan):
+            continue
+        plan = leaf.unstacked()
+        reps = max(1, leaf.factors[0].size // plan.factors[0].size)
+        experts = any(getattr(k, "key", None) == "experts" for k in path)
+        names = (plan.kernel_candidates(act_quantize)
+                 if use_pallas and not experts else ())
+        path = "jnp" if not names else next(
+            (n for n in names
+             if kops.vmem_fits(n, m, **plan.fit_geometry(n))), "ref")
+        out[path] = out.get(path, 0) + reps
+    return dict(sorted(out.items()))

@@ -51,10 +51,6 @@ IDX_SUFFIX = "_idx"
 INT8_QMAX = 127.0          # symmetric narrow range [-127, 127]
 FP8_MAX = 448.0            # e4m3 max finite
 
-# fp8 storage dtype; gated because very old jax lacks it (mode="fp8"
-# then raises rather than silently misreporting e4m3 numerics).
-_FP8_DTYPE = getattr(jnp, "float8_e4m3fn", None)
-
 
 def quantize_array(w: jax.Array, mode: str = MODE_INT8, *,
                    axis: int = -2) -> tuple[jax.Array, jax.Array]:
@@ -76,11 +72,7 @@ def quantize_array(w: jax.Array, mode: str = MODE_INT8, *,
         q = jnp.clip(jnp.round(scaled), -INT8_QMAX, INT8_QMAX
                      ).astype(jnp.int8)
     else:
-        if _FP8_DTYPE is None:
-            raise NotImplementedError(
-                "fp8 quantization needs jnp.float8_e4m3fn (jax too old); "
-                "use mode='int8'")
-        q = scaled.astype(_FP8_DTYPE)
+        q = scaled.astype(jnp.float8_e4m3fn)
     return q, scale
 
 

@@ -337,6 +337,7 @@ class ServeEngine:
         self.plan_summary["kv_layout"] = kv_layout
         if self.pool.plans:
             self.plan_summary["kv_cache_family"] = self.pool.plans[0].family
+        self.plan_summary["paths"] = self._path_census()
         self.key = jax.random.PRNGKey(seed)
         self.stats: deque[dict] = deque(maxlen=stats_window)
         # per-priority-class latency sample rings (seconds), bounded
@@ -360,6 +361,30 @@ class ServeEngine:
         #: time) — the clock the class ITL rings sample against
         self.service_s = 0.0
         self._step_token_reqs: list = []
+
+    def _path_census(self) -> dict:
+        """How each step kind executes, decided from the shapes it runs
+        at: per linear a fused kernel, ``"ref"`` (a kernel serves the
+        layout but its geometry does not fit VMEM, so the jnp reference
+        runs) or ``"jnp"``; and the same for decode attention.  Prefill
+        is costed at its largest segment (a chunk, or ``max_seq`` for
+        blocking admission)."""
+        from repro.layers import plan as lplan
+        use_pallas = self.opts.use_pallas
+        prefill_m = (self.prefill_chunk if self.admission == "continuous"
+                     else self.max_seq)
+        out = {
+            "decode": lplan.kernel_census(self.plans, self.slots,
+                                          use_pallas=use_pallas),
+            "prefill": lplan.kernel_census(
+                self.plans, prefill_m, use_pallas=use_pallas,
+                act_quantize=self.act_quantize == "int8"),
+        }
+        if self.pool.plans:
+            out["decode_attention"] = self.pool.plans[0].decode_path(
+                self.run.model.num_heads, self.slots, self.max_seq,
+                use_pallas)
+        return out
 
     def _supports_chunked(self) -> bool:
         return self.run.model.family in self._CHUNK_FAMILIES
@@ -529,10 +554,11 @@ class ServeEngine:
                                              for ps in started])
         now = time.perf_counter()
         first = 0
-        for ps, tok, flagged in zip(started, toks, bad):
+        for ps, tok, row, flagged in zip(started, toks, rows, bad):
             if flagged:
                 self._quarantine(ps.slot)
                 continue
+            self._keep_logits(ps.req, row)
             self._append_token(ps.req, int(tok), now)
             first += 1
             self._maybe_finish(ps.slot)
@@ -600,10 +626,16 @@ class ServeEngine:
             if flagged:
                 self._quarantine(ps.slot)
                 continue
+            self._keep_logits(ps.req, ps.last_logits)
             self._append_token(ps.req, int(tok), now)
             first += 1
             self._maybe_finish(ps.slot)
         return first
+
+    @staticmethod
+    def _keep_logits(req: Request, logits: jax.Array) -> None:
+        if req.keep_logits and req.first_logits is None:
+            req.first_logits = logits
 
     # -- lifecycle: cancel / deadlines --------------------------------------
 

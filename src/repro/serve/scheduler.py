@@ -85,8 +85,13 @@ class Request:
     #: one of :data:`PRIORITIES` — interactive streams decode/admit
     #: first, batch fills residual budget
     priority: str = "interactive"
+    #: keep the (V,) f32 logits the first token is sampled from (the
+    #: prompt's last position) in ``first_logits`` — for comparing
+    #: serving paths by their logits
+    keep_logits: bool = False
     # filled by the engine:
     output: list[int] = dataclasses.field(default_factory=list)
+    first_logits: Any = None
     done: bool = False
     #: one of :data:`STATUSES` once terminal, else ``None``
     status: str | None = None

@@ -52,6 +52,31 @@ class TestSVD:
         f = svd.randomized_svd(w, 16)
         assert svd.approximation_error(w, f) < 1e-3
 
+    @pytest.mark.parametrize("shape,rank", [
+        ((64, 48), 48), ((48, 200), 20), ((200, 48), 20), ((3, 96, 64), 32)])
+    def test_gram_svd_matches_svd(self, rng, shape, rank):
+        """The Gram-matrix path (decompose_auto's) gives svd_decompose's
+        rank-r product and balanced factor norms, wide or tall, batched
+        over leading dims."""
+        w = jax.random.normal(rng, shape)
+        want = svd.svd_decompose(w, rank)
+        got = svd.gram_svd_decompose(w, rank)
+        assert got.w0.shape == want.w0.shape and got.w1.shape == want.w1.shape
+        np.testing.assert_allclose(np.asarray(svd.reconstruct(got)),
+                                   np.asarray(svd.reconstruct(want)),
+                                   atol=2e-4)
+        np.testing.assert_allclose(float(jnp.linalg.norm(got.w0)),
+                                   float(jnp.linalg.norm(got.w1)), rtol=1e-4)
+
+    def test_gram_svd_rank_deficient(self, rng):
+        """Directions with no signal get zero factors, not inf/nan."""
+        a, b = jax.random.split(rng)
+        w = jax.random.normal(a, (64, 4)) @ jax.random.normal(b, (4, 96))
+        f = svd.gram_svd_decompose(w, 16)
+        assert np.all(np.isfinite(np.asarray(f.w1)))
+        np.testing.assert_allclose(np.asarray(svd.reconstruct(f)),
+                                   np.asarray(w), atol=1e-3)
+
     def test_host_twin_matches(self, rng):
         w = np.asarray(jax.random.normal(rng, (32, 48)))
         w0, w1 = svd.host_svd_decompose(w, 16)

@@ -103,6 +103,45 @@ class TestKernelEligibility:
             .kernel_for((8, 64), True) is None
 
 
+class TestKernelCensus:
+    """The engine's plan summary says which linears run a kernel, which
+    fall back to the jnp reference because they do not fit, and which
+    no kernel serves — for stacked (scanned) plans too."""
+
+    def _tree(self, rng):
+        return {"blocks": {"up": {"w0": jnp.zeros((4, 128, 32)),
+                                  "w1": jnp.zeros((4, 32, 64))}},
+                "head": _lowrank(rng),
+                "embed": {"w": jnp.zeros((64, 128))}}
+
+    def test_counts_per_scan_step(self, rng):
+        plans = lplan.build_plan_tree(self._tree(rng))
+        assert lplan.kernel_census(plans, 8, use_pallas=True) == \
+            {"jnp": 1, "lowrank": 5}
+        assert lplan.kernel_census(plans, 8, use_pallas=False) == \
+            {"jnp": 6}
+
+    def test_expert_banks_run_jnp(self):
+        bank = {"w0": jnp.zeros((2, 4, 128, 32)),
+                "w1": jnp.zeros((2, 4, 32, 64))}
+        plans = lplan.build_plan_tree({"moe": {"experts": {"up": bank}}})
+        assert lplan.kernel_census(plans, 8, use_pallas=True) == {"jnp": 8}
+
+    def test_unfit_kernel_counts_as_ref(self, rng, monkeypatch):
+        from repro.kernels import ops
+        monkeypatch.setattr(ops, "VMEM_BUDGET", 0)
+        plans = lplan.build_plan_tree(self._tree(rng))
+        assert lplan.kernel_census(plans, 8, use_pallas=True) == \
+            {"jnp": 1, "ref": 5}
+
+    def test_quantized_prefill_prefers_act_quant_kernel(self, rng):
+        plans = lplan.build_plan_tree({"head": quantize_tree(_lowrank(rng))})
+        assert lplan.kernel_census(plans, 64, use_pallas=True,
+                                   act_quantize=True) == {"lowrank_qa": 1}
+        assert lplan.kernel_census(plans, 64, use_pallas=True) == \
+            {"lowrank_q": 1}
+
+
 class TestExecution:
     @pytest.mark.parametrize("quant", [False, True])
     def test_lowrank_pallas_matches_reference_3d(self, quant, rng):

@@ -24,13 +24,14 @@ import jax.numpy as jnp
 from repro.configs import registry
 from repro.configs.base import LRDConfig, ParallelConfig, RunConfig
 from repro.core.surgery import decompose_model
+from repro.launch.mesh import make_mesh
 from repro.models.api import get_model
 from repro.parallel import sharding as shd
 from repro.quant import quantize_tree
 from repro.serve.engine import Request, ServeEngine
 
 assert len(jax.devices()) == 2, jax.devices()
-mesh = jax.make_mesh((1, 2), ("data", "model"))
+mesh = make_mesh((1, 2), ("data", "model"))
 
 cfg = registry.get("llama3.2-1b").smoke
 # branches=2 with a small align so some layers branch and the rest take

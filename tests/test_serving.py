@@ -92,3 +92,21 @@ class TestServeEngine:
         eng.add_request(req)
         eng.run_until_done()
         assert req.done and len(req.output) == 4
+
+    @pytest.mark.parametrize("admission", ["continuous", "blocking"])
+    def test_keep_logits_holds_first_token_logits(self, setup, admission):
+        """A request that asks keeps the logits its first token was
+        sampled from; others keep nothing."""
+        run, m, params = setup
+        eng = ServeEngine(run, params, slots=2, max_seq=64,
+                          admission=admission)
+        kept = Request(uid=0, prompt=[5, 6, 7, 8], max_new_tokens=3,
+                       keep_logits=True)
+        plain = Request(uid=1, prompt=[5, 6, 7, 8], max_new_tokens=3)
+        for r in (kept, plain):
+            eng.add_request(r)
+        eng.run_until_done()
+        assert plain.first_logits is None
+        logits = np.asarray(kept.first_logits)
+        assert logits.shape == (m.padded_vocab,)
+        assert int(np.argmax(logits)) == kept.output[0] == plain.output[0]

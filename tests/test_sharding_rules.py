@@ -2,9 +2,11 @@
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ParallelConfig
+from repro.launch.mesh import make_mesh
 from repro.layers.param import (EMBED, EXPERTS, FFN, LAYERS, QKV, RANK,
                                 VOCAB)
 from repro.parallel import sharding as shd
@@ -13,7 +15,7 @@ from repro.parallel import sharding as shd
 @pytest.fixture(scope="module")
 def mesh():
     # single CPU device: (1, 1) mesh — rule resolution is shape-logic only
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def spec_of(mesh, axes, shape, parallel):
@@ -51,7 +53,7 @@ class TestParamRules:
         assert spec_of(mesh, (RANK, FFN), (8, 128), par) == P(None, "model")
 
     def test_indivisible_replicates_with_note(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         par = ParallelConfig()
         notes = []
         tree_p = {"x": jax.ShapeDtypeStruct((7, 13), jnp.float32)}
@@ -133,7 +135,7 @@ class TestQuantizedParamRules:
         assert qa["up"]["w1_scale"] == scale_axes((RANK, FFN)) == (NONE, FFN)
         assert qa["norm"]["scale"] == (EMBED,)          # untouched
         # rewritten axes resolve without the alignment fallback too
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         s = shd.make_param_shardings(mesh, qp, qa, ParallelConfig(fsdp=True))
         assert s["up"]["w1_scale"].spec == P(None, "model")
 
@@ -160,11 +162,8 @@ class TestCacheRules:
     def test_b1_decode_seq_both_axes(self):
         # abstract 16x16 mesh: B=1 is NOT divisible by data -> the seq dim
         # takes both axes (the long_500k decode layout)
-        try:
-            mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
-        except TypeError:   # older jax: one tuple of (name, size) pairs
-            mesh = jax.sharding.AbstractMesh(
-                (("data", 16), ("model", 16)))
+        mesh = jax.sharding.AbstractMesh(
+            (16, 16), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         par = ParallelConfig(decode_seq_shard=True)
         spec = {"k": jax.ShapeDtypeStruct((2, 1, 512, 2, 16), jnp.bfloat16)}
         got = shd.cache_shardings(mesh, spec, par, batch=1, seq_len=512)
