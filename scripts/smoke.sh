@@ -1,19 +1,8 @@
 #!/usr/bin/env bash
-# Tier-1 smoke: the full test suite + the quant benchmarks in CPU
-# interpret mode. This is what CI runs (see .github/workflows/smoke.yml).
+# Tier-1 smoke: the full test suite, Pallas kernels in CPU interpret
+# mode. This is what CI runs (see .github/workflows/smoke.yml).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
 python -m pytest -x -q
-python -m benchmarks.run --list
-python -m benchmarks.bench_quant --dry-run
-python -m benchmarks.bench_branched_quant --dry-run
-python -m benchmarks.bench_serve_decode --sweep kv --dry-run
-python -m benchmarks.bench_serve_decode --sweep mla --dry-run
-python -m benchmarks.bench_serve_decode --sweep sched --dry-run
-python -m benchmarks.bench_serve_decode --sweep paged --dry-run
-python -m benchmarks.bench_serve_decode --sweep faults --dry-run
-python -m benchmarks.bench_serve_decode --sweep prefill --dry-run
-python -m benchmarks.bench_serve_decode --sweep router --dry-run
-python -m benchmarks.bench_frontier --dry-run

@@ -24,7 +24,7 @@ shares geometry, hence rank, which keeps ``lax.scan`` homogeneous.
 
 Model code never changes: ``apply_linear`` / ``apply_conv`` dispatch on the
 keys present.  The surgery also emits a :class:`SurgeryReport` with the
-per-layer decisions and param/FLOP accounting used by the benchmarks.
+per-layer decisions and param/FLOP accounting.
 """
 from __future__ import annotations
 

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import tracing
 from repro.layers import cache as cache_mod
 from repro.layers.cache import CachePlan
 from repro.layers.param import (
@@ -197,42 +198,54 @@ def apply_attention(p: dict, x: jax.Array, *, num_heads: int,
     b, sq, _ = x.shape
     kw = dict(freeze_factors=opts.freeze_factors, use_pallas=opts.use_pallas,
               act_quantize=opts.act_quantize)
-    q = apply_linear(p["q"], x, **kw).reshape(b, sq, num_heads, head_dim)
-    k = apply_linear(p["k"], x, **kw).reshape(b, sq, num_kv_heads, head_dim)
-    v = apply_linear(p["v"], x, **kw).reshape(b, sq, num_kv_heads, head_dim)
-
-    sin, cos = rope_sincos(positions, head_dim, rope_theta)
-    q = apply_rope(q, sin, cos)
-    k = apply_rope(k, sin, cos)
-    q = shard_act(q, BATCH, SEQ, HEADS, HEAD_DIM)
-    k = shard_act(k, BATCH, SEQ, KV_HEADS, HEAD_DIM)
-    v = shard_act(v, BATCH, SEQ, KV_HEADS, HEAD_DIM)
+    with jax.named_scope(tracing.QKV_PROJ):
+        q = apply_linear(p["q"], x, **kw).reshape(b, sq, num_heads, head_dim)
+        k = apply_linear(p["k"], x, **kw).reshape(b, sq, num_kv_heads,
+                                                  head_dim)
+        v = apply_linear(p["v"], x, **kw).reshape(b, sq, num_kv_heads,
+                                                  head_dim)
+        sin, cos = rope_sincos(positions, head_dim, rope_theta)
+        q = apply_rope(q, sin, cos)
+        k = apply_rope(k, sin, cos)
+        q = shard_act(q, BATCH, SEQ, HEADS, HEAD_DIM)
+        k = shard_act(k, BATCH, SEQ, KV_HEADS, HEAD_DIM)
+        v = shard_act(v, BATCH, SEQ, KV_HEADS, HEAD_DIM)
 
     new_cache = None
     if cache is None:
-        o = chunked_attention(q, k, v, causal=causal, softcap=opts.softcap)
+        with jax.named_scope(tracing.ATTEND):
+            o = chunked_attention(q, k, v, causal=causal,
+                                  softcap=opts.softcap)
     else:
         if plan is None:
             plan = cache_mod.plan_from_cache(cache, x.dtype)
         if cache_pos is not None:    # decode: per-slot positions (B,)
             assert sq == 1, sq
-            new_cache = plan.write_decode(cache, {"k": k[:, 0], "v": v[:, 0]},
-                                          cache_pos)
-            o = plan.attend_decode(q, new_cache, cache_pos,
-                                   softcap=opts.softcap,
-                                   use_pallas=opts.use_pallas)
+            with jax.named_scope(tracing.KV_WRITE):
+                new_cache = plan.write_decode(
+                    cache, {"k": k[:, 0], "v": v[:, 0]}, cache_pos)
+            with jax.named_scope(tracing.ATTEND):
+                o = plan.attend_decode(q, new_cache, cache_pos,
+                                       softcap=opts.softcap,
+                                       use_pallas=opts.use_pallas)
         elif start_pos is not None:  # prefill chunk at a sequence offset
-            new_cache, view = plan.write_chunk(cache, {"k": k, "v": v},
-                                               start_pos, prompt_len)
-            o = chunked_attention(q, view["k"], view["v"], causal=causal,
-                                  q_offset=start_pos, softcap=opts.softcap)
+            with jax.named_scope(tracing.KV_WRITE):
+                new_cache, view = plan.write_chunk(cache, {"k": k, "v": v},
+                                                   start_pos, prompt_len)
+            with jax.named_scope(tracing.ATTEND):
+                o = chunked_attention(q, view["k"], view["v"],
+                                      causal=causal, q_offset=start_pos,
+                                      softcap=opts.softcap)
         else:                        # prefill (any length, incl. 1 token)
-            new_cache = plan.write_prefill(cache, {"k": k, "v": v},
-                                           prompt_len)
-            o = chunked_attention(q, k, v, causal=causal,
-                                  softcap=opts.softcap)
-    o = o.reshape(b, sq, num_heads * head_dim)
-    out = apply_linear(p["o"], o, **kw)
+            with jax.named_scope(tracing.KV_WRITE):
+                new_cache = plan.write_prefill(cache, {"k": k, "v": v},
+                                               prompt_len)
+            with jax.named_scope(tracing.ATTEND):
+                o = chunked_attention(q, k, v, causal=causal,
+                                      softcap=opts.softcap)
+    with jax.named_scope(tracing.O_PROJ):
+        o = o.reshape(b, sq, num_heads * head_dim)
+        out = apply_linear(p["o"], o, **kw)
     return out, new_cache
 
 

@@ -11,6 +11,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.layers import attention as attn
 from repro.layers import cache as cache_mod
 from repro.layers import ssm as ssm_mod
@@ -84,7 +85,8 @@ def apply_block(p: dict, x: jax.Array, cfg, *, positions, cache=None,
     """
     _, norm = _norm_fns(cfg)
     causal = not cfg.is_encoder
-    h = norm(p["attn_norm"], x, cfg.norm_eps)
+    with jax.named_scope(tracing.NORM):
+        h = norm(p["attn_norm"], x, cfg.norm_eps)
     if "mla" in p:
         a, new_cache = attn.apply_mla(
             p["mla"], h, cfg, positions=positions, causal=causal,
@@ -105,15 +107,17 @@ def apply_block(p: dict, x: jax.Array, cfg, *, positions, cache=None,
             start_pos=start_pos, plan=cache_plan,
             opts=opts.attn(cfg.attn_logit_softcap))
     x = x + a
-    h = norm(p["mlp_norm"], x, cfg.norm_eps)
+    with jax.named_scope(tracing.NORM):
+        h = norm(p["mlp_norm"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
-    if "moe" in p:
-        f, aux = apply_moe(p["moe"], h, top_k=cfg.moe_top_k,
-                           capacity_factor=cfg.moe_capacity_factor,
-                           act=cfg.act, opts=opts.moe(),
-                           dispatch_groups=cfg.moe_dispatch_groups)
-    else:
-        f = apply_mlp(p["mlp"], h, cfg.act, **opts.kw())
+    with jax.named_scope(tracing.MLP):
+        if "moe" in p:
+            f, aux = apply_moe(p["moe"], h, top_k=cfg.moe_top_k,
+                               capacity_factor=cfg.moe_capacity_factor,
+                               act=cfg.act, opts=opts.moe(),
+                               dispatch_groups=cfg.moe_dispatch_groups)
+        else:
+            f = apply_mlp(p["mlp"], h, cfg.act, **opts.kw())
     x = x + f
     x = shard_act(x, BATCH, SEQ, EMBED)
     return x, new_cache, aux

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.layers import cache as cache_mod
 from repro.layers import ssm as ssm_mod
@@ -149,6 +150,10 @@ class LMModel:
     # -- embedding / head -----------------------------------------------------
 
     def embed(self, params: PyTree, batch: dict) -> jax.Array:
+        with jax.named_scope(tracing.EMBED):
+            return self._embed(params, batch)
+
+    def _embed(self, params: PyTree, batch: dict) -> jax.Array:
         cfg = self.cfg
         if cfg.family == "encoder":
             x = batch["frames"].astype(self.dtype)
@@ -162,6 +167,11 @@ class LMModel:
 
     def logits(self, params: PyTree, x: jax.Array,
                opts: B.BlockOpts = B.BlockOpts()) -> jax.Array:
+        with jax.named_scope(tracing.UNEMBED):
+            return self._logits(params, x, opts)
+
+    def _logits(self, params: PyTree, x: jax.Array,
+                opts: B.BlockOpts) -> jax.Array:
         cfg = self.cfg
         norm = layer_norm if cfg.family == "encoder" else rms_norm
         h = norm(params["final_norm"], x, cfg.norm_eps)
@@ -182,10 +192,16 @@ class LMModel:
 
     # -- trunk ---------------------------------------------------------------
 
-    def trunk(self, params: PyTree, x: jax.Array, *, positions, cache=None,
-              cache_pos=None, batch=None, opts=B.BlockOpts(),
-              remat: str = "none", prompt_len=None, start_pos=None,
-              cache_plan=None) -> tuple[jax.Array, PyTree, jax.Array]:
+    def trunk(self, params: PyTree, x: jax.Array, **kw
+              ) -> tuple[jax.Array, PyTree, jax.Array]:
+        """:meth:`_trunk` under the ``layers`` scope."""
+        with jax.named_scope(tracing.LAYERS):
+            return self._trunk(params, x, **kw)
+
+    def _trunk(self, params: PyTree, x: jax.Array, *, positions, cache=None,
+               cache_pos=None, batch=None, opts=B.BlockOpts(),
+               remat: str = "none", prompt_len=None, start_pos=None,
+               cache_plan=None) -> tuple[jax.Array, PyTree, jax.Array]:
         """Run all blocks. Returns (x, new_cache, aux_loss_sum).
 
         ``prompt_len`` (scalar, prefill only) marks how many leading

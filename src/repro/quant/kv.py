@@ -277,7 +277,7 @@ def kv_write_token(cache_q: jax.Array, scale: jax.Array, new: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Accounting (cost model / benchmarks)
+# Accounting (cost model)
 # ---------------------------------------------------------------------------
 
 def kv_bytes_per_step(slots: int, seq_len: int, num_kv_heads: int,

@@ -249,7 +249,7 @@ def dequantize_tree(params: PyTree, dtype=jnp.bfloat16) -> PyTree:
 
 
 # ---------------------------------------------------------------------------
-# Accounting helpers (benchmarks / reports)
+# Accounting helpers (reports)
 # ---------------------------------------------------------------------------
 
 def tree_bytes(params: PyTree) -> int:

@@ -27,8 +27,7 @@ nominally buys); the shared mask needs one int8 position per kept *row*
 (``C/2`` bytes per factor, amortized over all S columns), so the byte
 gain stays ~2x.  The trade is coarser pruning — acceptable on low-rank
 factors, whose rows are energy-sorted by construction (the SVD already
-concentrated magnitude), and measured end-to-end by
-``benchmarks/bench_frontier.py``'s ``token_match`` column.
+concentrated magnitude).
 
 Slot-major packing (keep-slot as the leading axis, not interleaved)
 lets the fused kernels slice ``sp_ref[i]`` as a contiguous 2D tile —

@@ -38,8 +38,10 @@ AND runs the numerical watchdog (:mod:`repro.serve.guard`): a stream
 whose logits go non-finite is quarantined — terminated ``failed``, its
 slot/blocks reclaimed without publishing to the radix — while its
 co-batched neighbors' token streams stay bit-identical.  Per-step stats
-(a bounded ring buffer) record decode, prefill, and admission seconds;
-every request carries TTFT timestamps.
+(a bounded ring buffer) record each phase's host seconds (``phase_s``,
+:mod:`repro.tracing`; each phase is also a profiler span) and their
+decode, prefill and admission sums; every request carries submit,
+admission and token timestamps.
 
 Hardening (the serve twin of :mod:`repro.train.fault_tolerance`):
 
@@ -72,6 +74,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import RunConfig
 from repro.models.api import get_model
 from repro.serve import paging
@@ -328,6 +331,8 @@ class ServeEngine:
         self.quarantined = 0
         self.deadline_expired = 0
         self._step_idx = 0
+        #: the phase clock of the step in progress (or the last one)
+        self._clock = tracing.PhaseClock()
         # Decode streams the entire KV pool (masked, not skipped) every
         # step — the runtime twin of ``weight_bytes`` in the roofline,
         # and where kv_quantize="int8" pays.  Both numbers derive from
@@ -492,13 +497,12 @@ class ServeEngine:
             return True
         return False
 
-    def _sample_rows(self, rows: list[jax.Array],
-                     temps_list: list[float]
-                     ) -> tuple[np.ndarray, np.ndarray]:
-        """Sample k <= slots logits rows in ONE device call, padded to
-        the decode path's single compiled (slots, V) shape.  Returns
-        ``(tokens, bad)`` — ``bad`` is the fused watchdog's per-row
-        non-finite flag (padding rows are zeros, never flagged)."""
+    def _sample_inputs(self, rows: list[jax.Array],
+                       temps_list: list[float]
+                       ) -> tuple[jax.Array, jax.Array, np.ndarray]:
+        """Key, logits and temperatures to sample k <= slots logits rows
+        in ONE device call, padded to the decode path's single compiled
+        (slots, V) shape (padding rows are zeros, never flagged)."""
         k = len(rows)
         lg = jnp.stack(rows)
         if k < self.slots:
@@ -506,8 +510,7 @@ class ServeEngine:
         temps = np.zeros((self.slots,), np.float32)
         temps[:k] = temps_list
         self.key, sub = jax.random.split(self.key)
-        toks, bad = self.runner.sample(sub, lg, jnp.asarray(temps))
-        return toks[:k], bad[:k]
+        return sub, lg, temps
 
     def _quarantine(self, slot: int) -> None:
         """Numerical-watchdog casualty: terminate the stream in
@@ -520,116 +523,140 @@ class ServeEngine:
 
     # -- blocking admission (pre-scheduler path; recurrent/MoE/VLM) ---------
 
-    def _admit_blocking(self) -> tuple[int, int]:
+    def _prefill_whole(self, started: list[PrefillStream]
+                       ) -> tuple[int, int]:
         """One whole prefill per admitted request (admission policy is
         the Scheduler's — same resume/byte-budget rules as the chunked
         path).  Returns (first tokens sampled, prompt tokens prefilled)."""
-        started = self.scheduler.admit(self.pool)
         if not started:
             return 0, 0
+        clock = self._clock
         pf_toks = 0
-        rows: list[jax.Array] = []
         for ps in started:
-            n = len(ps.tokens)
-            padded = np.zeros((1, self._bucket_len(n)), np.int32)
-            padded[0, :n] = ps.tokens
-            prompt = jnp.asarray(padded)
-            cache1 = self.runner.new_stream_cache(
-                kv_quantize=self.kv_quantize)
-            if self.run.model.family == "vlm":
-                batch = {"tokens": prompt,
-                         "image_embeds": jnp.zeros(
-                             (1, self.run.model.num_image_tokens,
-                              self.run.model.d_model), self.model.dtype)}
-            else:
-                batch = {"tokens": prompt}
-            logits, cache1 = self.runner.step(
-                prompt, None, "prefill", cache=cache1, batch=batch,
-                last_pos=jnp.asarray(n - 1, jnp.int32))
-            self.pool.insert(cache1, ps.slot, n)
-            self.scheduler.activate(ps)
-            pf_toks += n
-            rows.append(logits[0, -1, :])
-        toks, bad = self._sample_rows(rows, [max(ps.req.temperature, 0.0)
-                                             for ps in started])
-        now = time.perf_counter()
-        first = 0
-        for ps, tok, row, flagged in zip(started, toks, rows, bad):
-            if flagged:
-                self._quarantine(ps.slot)
-                continue
-            self._keep_logits(ps.req, row)
-            self._append_token(ps.req, int(tok), now)
-            first += 1
-            self._maybe_finish(ps.slot)
-        return first, pf_toks
+            with clock.phase(tracing.PREFILL_DISPATCH, uid=ps.req.uid):
+                cache1 = self._dispatch_whole(ps)
+            with clock.phase(tracing.PREFILL_INSERT):
+                self.pool.insert(cache1, ps.slot, len(ps.tokens))
+                self.scheduler.activate(ps)
+            del cache1
+            pf_toks += len(ps.tokens)
+        return self._first_tokens(started), pf_toks
+
+    def _dispatch_whole(self, ps: PrefillStream) -> PyTree:
+        """Enqueue the whole-prompt prefill of ``ps`` into a fresh
+        staging cache in the pool's dtype; returns that cache."""
+        n = len(ps.tokens)
+        padded = np.zeros((1, self._bucket_len(n)), np.int32)
+        padded[0, :n] = ps.tokens
+        prompt = jnp.asarray(padded)
+        cache1 = self.runner.new_stream_cache(kv_quantize=self.kv_quantize)
+        if self.run.model.family == "vlm":
+            batch = {"tokens": prompt,
+                     "image_embeds": jnp.zeros(
+                         (1, self.run.model.num_image_tokens,
+                          self.run.model.d_model), self.model.dtype)}
+        else:
+            batch = {"tokens": prompt}
+        logits, cache1 = self.runner.step(
+            prompt, None, "prefill", cache=cache1, batch=batch,
+            last_pos=jnp.asarray(n - 1, jnp.int32))
+        ps.last_logits = logits[0, -1, :]
+        return cache1
 
     # -- continuous admission: chunked prefill under the token budget -------
 
-    def _prefill_chunks(self, n_live: int) -> tuple[int, int]:
+    def _prefill_chunks(self, n_live: int) -> tuple[int, int, int]:
         """Spend the step's leftover token budget on prefill chunks.
-        Returns (prompt tokens prefilled, first tokens sampled)."""
+        Returns (prompt tokens prefilled, first tokens sampled, chunks
+        dispatched)."""
         plan = self.scheduler.chunk_plan(n_live)
         if not plan:
-            return 0, 0
+            return 0, 0, 0
         completed: list[PrefillStream] = []
         pf_toks = 0
         for ps, c in plan:
-            if ps.cache is None:
-                # full-precision staging (even over an int8 pool): chunk
-                # attention sees the exact K/V prefix, the pool
-                # quantizes once at insert -> chunked == whole, bit-exact
-                ps.cache = self.runner.new_stream_cache()
-                if ps.written:
-                    # paged prefix hit: the first `written` positions'
-                    # KV is already pooled — gather it into the staging
-                    # cache (dequantizing int8 blocks) and chunk-prefill
-                    # only the suffix
-                    ps.cache = self.pool.gather_prefix(
-                        ps.cache, ps.slot, ps.written)
-            b = self._bucket_len(c)
-            if ps.written + b > self.max_seq:   # keep the offset write
-                b = self.max_seq - ps.written   # inside the slot
-            padded = np.zeros((1, b), np.int32)
-            padded[0, :c] = ps.tokens[ps.written:ps.written + c]
-            # prompt_len = the chunk's real end: bucket-pad rows beyond
-            # it are zeroed at the K/V write (attention masks them), so
-            # correctness never depends on a later chunk overwriting
-            # them.  On the final chunk this is the prompt length, which
-            # also places the logits gather at the last real token.
-            eff_len = min(len(ps.tokens), ps.written + c)
-            logits, ps.cache = self.runner.step(
-                jnp.asarray(padded), None, "prefill_chunk", cache=ps.cache,
-                start_pos=jnp.asarray(ps.written, jnp.int32),
-                prompt_len=jnp.asarray(eff_len, jnp.int32))
-            ps.written += c
+            with self._clock.phase(tracing.PREFILL_DISPATCH, uid=ps.req.uid):
+                # the last chunk's logits stay referenced until the
+                # step's prefill ends: when device buffers are freed
+                # shapes the HBM layout, and the layout with this buffer
+                # freed early goes with the decode stall (PERF.md)
+                logits = self._dispatch_chunk(ps, c)
             pf_toks += c
-            ps.last_logits = logits[0, 0, :]
             if ps.remaining == 0:
                 completed.append(ps)
-        return pf_toks, self._finish_prefills(completed)
+        first = self._finish_prefills(completed)
+        del logits
+        return pf_toks, first, len(plan)
+
+    def _dispatch_chunk(self, ps: PrefillStream, c: int) -> jax.Array:
+        """Enqueue the chunk program for ``ps``'s next ``c`` tokens;
+        returns the chunk's logits."""
+        if ps.cache is None:
+            # full-precision staging (even over an int8 pool): chunk
+            # attention sees the exact K/V prefix, the pool quantizes
+            # once at insert -> chunked == whole, bit-exact
+            ps.cache = self.runner.new_stream_cache()
+            if ps.written:
+                # paged prefix hit: the first `written` positions' KV is
+                # already pooled — gather it into the staging cache
+                # (dequantizing int8 blocks) and chunk-prefill only the
+                # suffix
+                ps.cache = self.pool.gather_prefix(
+                    ps.cache, ps.slot, ps.written)
+        b = self._bucket_len(c)
+        if ps.written + b > self.max_seq:   # keep the offset write
+            b = self.max_seq - ps.written   # inside the slot
+        padded = np.zeros((1, b), np.int32)
+        padded[0, :c] = ps.tokens[ps.written:ps.written + c]
+        # prompt_len = the chunk's real end: bucket-pad rows beyond it
+        # are zeroed at the K/V write (attention masks them), so
+        # correctness never depends on a later chunk overwriting them.
+        # On the final chunk this is the prompt length, which also
+        # places the logits gather at the last real token.
+        eff_len = min(len(ps.tokens), ps.written + c)
+        logits, ps.cache = self.runner.step(
+            jnp.asarray(padded), None, "prefill_chunk", cache=ps.cache,
+            start_pos=jnp.asarray(ps.written, jnp.int32),
+            prompt_len=jnp.asarray(eff_len, jnp.int32))
+        ps.written += c
+        ps.last_logits = logits[0, 0, :]
+        return logits
 
     def _finish_prefills(self, completed: list[PrefillStream]) -> int:
+        """Land completed streams' staging caches in the pool and sample
+        their first tokens.  Returns first tokens sampled."""
         if not completed:
             return 0
-        for ps in completed:
-            self.pool.insert(ps.cache, ps.slot, len(ps.tokens),
-                             from_full_precision=True)
-            self.scheduler.activate(ps)
-            ps.cache = None
-        toks, bad = self._sample_rows([ps.last_logits for ps in completed],
-                                      [max(ps.req.temperature, 0.0)
-                                       for ps in completed])
-        now = time.perf_counter()
-        first = 0
-        for ps, tok, flagged in zip(completed, toks, bad):
-            if flagged:
-                self._quarantine(ps.slot)
-                continue
-            self._keep_logits(ps.req, ps.last_logits)
-            self._append_token(ps.req, int(tok), now)
-            first += 1
-            self._maybe_finish(ps.slot)
+        with self._clock.phase(tracing.PREFILL_INSERT):
+            for ps in completed:
+                self.pool.insert(ps.cache, ps.slot, len(ps.tokens),
+                                 from_full_precision=True)
+                self.scheduler.activate(ps)
+                ps.cache = None
+        return self._first_tokens(completed)
+
+    def _first_tokens(self, streams: list[PrefillStream]) -> int:
+        """Sample the first token of every stream in ``streams`` in ONE
+        device call and append it.  Returns first tokens sampled."""
+        clock = self._clock
+        with clock.phase(tracing.PREFILL_DISPATCH):
+            sub, lg, temps = self._sample_inputs(
+                [ps.last_logits for ps in streams],
+                [max(ps.req.temperature, 0.0) for ps in streams])
+        with clock.phase(tracing.PREFILL_SYNC):
+            toks, bad = self.runner.sample(sub, lg, jnp.asarray(temps))
+            del sub, lg
+        with clock.phase(tracing.PREFILL_EMIT):
+            now = time.perf_counter()
+            first = 0
+            for ps, tok, flagged in zip(streams, toks, bad):
+                if flagged:
+                    self._quarantine(ps.slot)
+                    continue
+                self._keep_logits(ps.req, ps.last_logits)
+                self._append_token(ps.req, int(tok), now)
+                first += 1
+                self._maybe_finish(ps.slot)
         return first
 
     @staticmethod
@@ -702,19 +729,27 @@ class ServeEngine:
     # -- main loop ----------------------------------------------------------
 
     def _decode_live(self, live: list[int]) -> int:
+        pool, clock = self.pool, self._clock
+        with clock.phase(tracing.DECODE_DISPATCH):
+            tokens = np.zeros((self.slots, 1), np.int32)
+            for i in live:
+                tokens[i, 0] = self.active[i].output[-1]
+            logits, pool.cache = self.runner.step(
+                jnp.asarray(tokens), jnp.asarray(pool.positions), "decode",
+                cache=pool.cache)
+            lg = logits[:, 0, :]
+            temps = np.zeros((self.slots,), np.float32)
+            for i in live:
+                temps[i] = max(self.active[i].temperature, 0.0)
+            self.key, sub = jax.random.split(self.key)
+        with clock.phase(tracing.DECODE_SYNC):
+            toks, bad = self.runner.sample(sub, lg, jnp.asarray(temps))
+        with clock.phase(tracing.DECODE_EMIT):
+            return self._emit_decoded(live, tokens, toks, bad)
+
+    def _emit_decoded(self, live: list[int], tokens: np.ndarray,
+                      toks: np.ndarray, bad: np.ndarray) -> int:
         pool = self.pool
-        tokens = np.zeros((self.slots, 1), np.int32)
-        for i in live:
-            tokens[i, 0] = self.active[i].output[-1]
-        logits, pool.cache = self.runner.step(
-            jnp.asarray(tokens), jnp.asarray(pool.positions), "decode",
-            cache=pool.cache)
-        lg = logits[:, 0, :]
-        temps = np.zeros((self.slots,), np.float32)
-        for i in live:
-            temps[i] = max(self.active[i].temperature, 0.0)
-        self.key, sub = jax.random.split(self.key)
-        toks, bad = self.runner.sample(sub, lg, jnp.asarray(temps))
         now = time.perf_counter()
         produced = 0
         for i in live:
@@ -748,54 +783,52 @@ class ServeEngine:
         pressure, admit (unless the load shedder pauses it), decode
         every live stream, then spend leftover budget on prefill
         chunks.  Returns tokens produced (decode + first tokens)."""
-        with self._on_device():
+        self._step_idx += 1
+        with self._on_device(), jax.profiler.StepTraceAnnotation(
+                tracing.STEP, step_num=self._step_idx):
             return self._step()
 
     def _step(self) -> int:
         sched, pool = self.scheduler, self.pool
-        self._step_idx += 1
         self._step_token_reqs.clear()
-        self.stragglers.start()
-        self._expire_deadlines()
-        victims = pool.pressure_victims()
-        for slot in victims:
-            sched.preempt(slot)
-            pool.release(slot)
+        clock = self._clock = tracing.PhaseClock()
         admit_fail0 = sched.admit_failures
-        shed = False
-        if self.shedder is not None:
-            # degraded mode: run with the shrunk budget; pause
-            # admission only while work is already in flight (an idle
-            # engine must always admit — shedding can never deadlock
-            # the queue)
-            sched.step_token_budget = self.shedder.budget
-            shed = self.shedder.engaged and (
-                bool(sched.prefilling)
-                or any(r is not None for r in sched.active))
+        with clock.phase(tracing.SCHEDULE):
+            self._expire_deadlines()
+            victims = pool.pressure_victims()
+            for slot in victims:
+                sched.preempt(slot)
+                pool.release(slot)
+            shed = False
+            if self.shedder is not None:
+                # degraded mode: run with the shrunk budget; pause
+                # admission only while work is already in flight (an
+                # idle engine must always admit — shedding can never
+                # deadlock the queue)
+                sched.step_token_budget = self.shedder.budget
+                shed = self.shedder.engaged and (
+                    bool(sched.prefilling)
+                    or any(r is not None for r in sched.active))
+            started = [] if shed else sched.admit(pool)
         if self.admission == "blocking":
-            t0 = time.perf_counter()
-            first, pf_toks = (0, 0) if shed else self._admit_blocking()
-            admit_s = time.perf_counter() - t0
+            first, pf_toks = self._prefill_whole(started)
+            chunks = len(started)
             live = sched.live_slots()
-            produced, decode_s, prefill_s = 0, 0.0, 0.0
-            if live:
-                t0 = time.perf_counter()
-                produced = self._decode_live(live)
-                decode_s = time.perf_counter() - t0
+            produced = self._decode_live(live) if live else 0
             record = bool(live or first)
         else:
-            if not shed:
-                sched.admit(pool)
             live = sched.live_slots()
-            t0 = time.perf_counter()
             produced = self._decode_live(live) if live else 0
-            decode_s = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            pf_toks, first = self._prefill_chunks(len(live))
-            prefill_s = time.perf_counter() - t0
-            admit_s = 0.0
+            pf_toks, first, chunks = self._prefill_chunks(len(live))
             record = bool(live or pf_toks or first)
-        event = self.stragglers.stop(self._step_idx)
+        decode_s = clock.total(tracing.DECODE_PHASES)
+        prefill_s = clock.total(tracing.PREFILL_PHASES)
+        # blocking admission prefills whole prompts as it admits: its
+        # prefill time has always been reported as admission time
+        admit_s = prefill_s if self.admission == "blocking" else 0.0
+        if self.admission == "blocking":
+            prefill_s = 0.0
+        event = self.stragglers.observe(self._step_idx, clock.total())
         if self.shedder is not None:
             self.shedder.observe(bool(victims)
                                  or sched.admit_failures > admit_fail0
@@ -812,7 +845,10 @@ class ServeEngine:
                                "admit_failures":
                                    sched.admit_failures - admit_fail0,
                                "shed": int(shed),
-                               "straggler": int(event is not None)})
+                               "straggler": int(event is not None),
+                               "phase_s": clock.seconds,
+                               "chunks": chunks,
+                               "admitted": len(started)})
         # service-time ITL: every non-first token produced this step
         # samples the service seconds since the stream's previous token
         # (usually exactly this step's duration; preemption gaps span
@@ -905,7 +941,11 @@ class ServeEngine:
         """Aggregate serving stats over the (bounded) stats window.
         Unlike the pre-split engine, the denominator includes the time
         spent admitting/prefilling, not just decode steps — and TTFT is
-        reported from per-request timestamps.
+        reported from per-request timestamps.  ``prefill_seconds`` is
+        host time (chunk dispatch, pool insert, the first-token sync),
+        not prefill device time: a chunk that is not a prompt's last is
+        never waited for, so its device time shows only in a profiler
+        trace.
 
         The key set is identical whether or not any productive step was
         recorded (an idle engine reports zeros, not a narrower dict) —

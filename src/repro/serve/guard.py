@@ -30,6 +30,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+
 __all__ = ["nonfinite_rows", "sample_and_flag", "ReplicaGuard",
            "ReplicaGuardPolicy"]
 
@@ -51,12 +53,14 @@ def sample_and_flag(key: jax.Array, logits: jax.Array,
     zeroed logits (token 0 under greedy) and are flagged for the engine
     to quarantine.
     """
-    bad = nonfinite_rows(logits)
-    clean = jnp.where(bad[:, None], 0.0, logits)
-    greedy = jnp.argmax(clean, axis=-1)
-    safe = jnp.where(temps > 0, temps, 1.0)
-    sampled = jax.random.categorical(key, clean / safe[:, None], axis=-1)
-    return jnp.where(temps > 0, sampled, greedy), bad
+    with jax.named_scope(tracing.SAMPLE):
+        bad = nonfinite_rows(logits)
+        clean = jnp.where(bad[:, None], 0.0, logits)
+        greedy = jnp.argmax(clean, axis=-1)
+        safe = jnp.where(temps > 0, temps, 1.0)
+        sampled = jax.random.categorical(key, clean / safe[:, None],
+                                         axis=-1)
+        return jnp.where(temps > 0, sampled, greedy), bad
 
 
 # ---------------------------------------------------------------------------

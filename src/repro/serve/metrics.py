@@ -1,9 +1,8 @@
 """Shared latency statistics for the serve tier.
 
 One canonical percentile implementation used by the engine's per-class
-stats, the router's SLO tracker, and (re-exported through
-``benchmarks/common.py``) every bench sweep — so "p99 ITL" always means
-the same interpolation everywhere a number is recorded or compared.
+stats and the router's SLO tracker — so "p99 ITL" always means the same
+interpolation everywhere the serve tier records or compares one.
 """
 from __future__ import annotations
 

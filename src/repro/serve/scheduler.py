@@ -36,6 +36,7 @@ layout is the :class:`repro.layers.cache.CachePlan`'s concern.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Any
 
@@ -97,6 +98,9 @@ class Request:
     status: str | None = None
     # timing / lifecycle bookkeeping (engine-filled):
     submit_time: float | None = None
+    #: when the request first left the waiting queue for a slot; a
+    #: preempted request keeps its first stamp
+    admit_time: float | None = None
     first_token_time: float | None = None
     token_times: list[float] = dataclasses.field(default_factory=list)
     preemptions: int = 0
@@ -306,6 +310,8 @@ class Scheduler:
                 self.admit_failures += 1
                 break
             self.waiting.popleft()
+            if req.admit_time is None:
+                req.admit_time = time.perf_counter()
             ps = PrefillStream(req, slot, toks, written=matched)
             self.prefilling.append(ps)
             started.append(ps)

@@ -44,6 +44,10 @@ class StragglerDetector:
         assert self._t0 is not None, "stop() without start()"
         dt = time.perf_counter() - self._t0
         self._t0 = None
+        return self.observe(step, dt)
+
+    def observe(self, step: int, dt: float) -> StragglerEvent | None:
+        """Judge one step of ``dt`` seconds, timed by the caller."""
         self._n += 1
         if self.ewma is None:
             self.ewma = dt

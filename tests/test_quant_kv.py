@@ -393,16 +393,16 @@ class TestPrefillBucketing:
 
 
 class TestTokenMatchRegression:
-    """Fixed-seed pin of the int8-KV greedy agreement the serve bench
-    records (BENCH_serve.json, slots=2/s_max=64: 0.9688 — i.e. 31 of
-    32 tokens).  A silent drop here means a KV-quant accuracy
-    regression that the allclose tests are too loose to catch."""
+    """Fixed-seed pin of the int8-KV greedy agreement at slots=2 /
+    s_max=64: 0.9688, i.e. 31 of 32 tokens.  A silent drop here means
+    a KV-quant accuracy regression that the allclose tests are too
+    loose to catch."""
 
-    PINNED = 31 / 32                  # the bench's 0.9688, unrounded
+    PINNED = 31 / 32                  # 0.9688, unrounded
 
     def test_int8_kv_decode_token_match_pinned(self, serve_setup):
-        # Exact replica of the bench's (2, 64) sweep point: 4 requests
-        # whose prompt lengths straddle two power-of-2 buckets.
+        # 4 requests on (2 slots, 64 positions) whose prompt lengths
+        # straddle two power-of-2 buckets.
         run, m, params = serve_setup
         prompts = tuple(tuple([(i % 7) + 1] * (3 + (i % 8)))
                         for i in range(4))

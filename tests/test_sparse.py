@@ -249,8 +249,8 @@ class TestSparsePlan:
         p = lplan.build_plan(tree)
         # packed values only: half the logical counts
         assert p.param_count == (32 * 16 + 16 * 48) // 2
-        # the tree-walk twin (benchmarks.common.param_count semantics):
-        # *_idx and *_scale leaves are metadata, *_sp values count
+        # the tree-walk twin: *_idx and *_scale leaves are metadata,
+        # *_sp values count
         walked = sum(
             int(leaf.size)
             for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]
