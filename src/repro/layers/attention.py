@@ -6,8 +6,10 @@ tensor never exceeds ``chunk x kv_len`` per (batch, head) — the jnp analogue
 of flash attention, and the shape the TPU splash kernel would take.
 
 Decode attends one token against a cache of ``S`` slots; the new token's K/V
-is written at ``pos`` via dynamic_update_slice (works on sharded dims under
-GSPMD).
+is scattered in at each slot's ``pos``.  Under the model's layer scan the
+cache is the whole stack ``(L, ...)`` with a traced ``layer`` index: the
+write lands in the stacked buffer in place and attention reads the layer
+back from it.
 
 Cache layout is the :class:`repro.layers.cache.CachePlan`'s concern:
 one plan per attention layer declares the family (``gqa_f32 |
@@ -169,6 +171,7 @@ def apply_attention(p: dict, x: jax.Array, *, num_heads: int,
                     prompt_len: jax.Array | None = None,
                     start_pos: jax.Array | None = None,
                     plan: CachePlan | None = None,
+                    layer: jax.Array | None = None,
                     opts: AttnOpts = AttnOpts()) -> tuple[jax.Array, dict | None]:
     """Self-attention. Returns (output, updated_cache).
 
@@ -193,7 +196,9 @@ def apply_attention(p: dict, x: jax.Array, *, num_heads: int,
 
     ``plan`` is the layer's :class:`repro.layers.cache.CachePlan`; when
     None it is classified from the cache once (static metadata, safe
-    under jit).
+    under jit).  ``layer`` (traced scalar) marks ``cache`` as every
+    layer's stacked cache, of which this is layer ``layer``; the
+    returned cache is then the whole stack, written in place.
     """
     b, sq, _ = x.shape
     kw = dict(freeze_factors=opts.freeze_factors, use_pallas=opts.use_pallas,
@@ -223,15 +228,23 @@ def apply_attention(p: dict, x: jax.Array, *, num_heads: int,
             assert sq == 1, sq
             with jax.named_scope(tracing.KV_WRITE):
                 new_cache = plan.write_decode(
-                    cache, {"k": k[:, 0], "v": v[:, 0]}, cache_pos)
+                    cache, {"k": k[:, 0], "v": v[:, 0]}, cache_pos, layer)
+            if layer is not None:
+                # read the layer out of the stack only once the query is
+                # ready: read earlier, its slice stays live across the
+                # query's projection and XLA keeps it in HBM, a copy of
+                # the whole layer, where read late it is staged on core
+                # as attention's own read
+                q, new_cache = lax.optimization_barrier((q, new_cache))
             with jax.named_scope(tracing.ATTEND):
-                o = plan.attend_decode(q, new_cache, cache_pos,
-                                       softcap=opts.softcap,
+                o = plan.attend_decode(q, plan.layer_view(new_cache, layer),
+                                       cache_pos, softcap=opts.softcap,
                                        use_pallas=opts.use_pallas)
         elif start_pos is not None:  # prefill chunk at a sequence offset
             with jax.named_scope(tracing.KV_WRITE):
                 new_cache, view = plan.write_chunk(cache, {"k": k, "v": v},
-                                                   start_pos, prompt_len)
+                                                   start_pos, prompt_len,
+                                                   layer)
             with jax.named_scope(tracing.ATTEND):
                 o = chunked_attention(q, view["k"], view["v"],
                                       causal=causal, q_offset=start_pos,
@@ -239,7 +252,7 @@ def apply_attention(p: dict, x: jax.Array, *, num_heads: int,
         else:                        # prefill (any length, incl. 1 token)
             with jax.named_scope(tracing.KV_WRITE):
                 new_cache = plan.write_prefill(cache, {"k": k, "v": v},
-                                               prompt_len)
+                                               prompt_len, layer)
             with jax.named_scope(tracing.ATTEND):
                 o = chunked_attention(q, k, v, causal=causal,
                                       softcap=opts.softcap)
@@ -359,6 +372,7 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, positions: jax.Array,
               prompt_len: jax.Array | None = None,
               start_pos: jax.Array | None = None,
               plan: CachePlan | None = None,
+              layer: jax.Array | None = None,
               opts: AttnOpts = AttnOpts()) -> tuple[jax.Array, dict | None]:
     """Multi-head latent attention. Decode uses the *absorbed* form:
     queries projected into the kv_lora latent space, attention runs entirely
@@ -375,6 +389,7 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, positions: jax.Array,
     ``prompt_len`` (scalar) marks the real end of a right-padded chunk
     or prompt — pad rows are zeroed at the latent write, mirroring the
     GQA path, so bucketed chunked prefill is exact for MLA stacks too.
+    ``layer`` marks a stacked cache, as in :func:`apply_attention`.
     """
     b, sq, _ = x.shape
     h, nope, rope_d = cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
@@ -390,27 +405,29 @@ def apply_mla(p: dict, x: jax.Array, cfg, *, positions: jax.Array,
     if cache is not None and cache_pos is not None:  # absorbed decode
         assert sq == 1, sq
         new_cache = plan.write_decode(
-            cache, {"ckv": ckv[:, 0], "krope": k_rope[:, 0]}, cache_pos)
+            cache, {"ckv": ckv[:, 0], "krope": k_rope[:, 0]}, cache_pos,
+            layer)
         # Absorbed decode: fold kv_b's K-half into q, V-half into output.
         wkv = _kv_b_matrix(p["kv_b"], cfg)             # (lora, h, nope+vd)
         wk, wv = wkv[..., :nope], wkv[..., nope:]
         q_lat = jnp.einsum("bqhn,lhn->bqhl", q_nope, wk)     # (B,1,H,lora)
-        ctx_lat = plan.attend_decode_latent(q_lat, q_rope, new_cache,
-                                            cache_pos, scale=scale,
-                                            use_pallas=opts.use_pallas)
+        ctx_lat = plan.attend_decode_latent(
+            q_lat, q_rope, plan.layer_view(new_cache, layer), cache_pos,
+            scale=scale, use_pallas=opts.use_pallas)
         o = jnp.einsum("bqhl,lhv->bqhv", ctx_lat, wv)
     else:
         if cache is not None and start_pos is not None:
             # chunk: write at the offset, attend over the whole cached
             # latent prefix (the plan's full-precision view)
             new_cache, view = plan.write_chunk(
-                cache, {"ckv": ckv, "krope": k_rope}, start_pos, prompt_len)
+                cache, {"ckv": ckv, "krope": k_rope}, start_pos, prompt_len,
+                layer)
             src_ckv, src_rope = view["ckv"], view["krope"]
             skv, q_off = src_ckv.shape[1], start_pos
         else:
             if cache is not None:   # whole prefill: fill the latent cache
                 new_cache = plan.write_prefill(
-                    cache, {"ckv": ckv, "krope": k_rope}, prompt_len)
+                    cache, {"ckv": ckv, "krope": k_rope}, prompt_len, layer)
             src_ckv, src_rope, skv, q_off = ckv, k_rope, sq, 0
         kv = apply_linear(p["kv_b"], src_ckv, **kw).reshape(b, skv, h,
                                                             nope + vd)

@@ -32,6 +32,13 @@ LinearPlan`: one plan per attention layer declaring
   which dispatches the fused int8 kernels behind the shared
   ``ops.kernel_fits`` gate).
 
+Every write executor takes either one layer's cache or, given a traced
+``layer`` index, the whole stack ``(L, ...)`` that the model's layer
+scan carries: the write then lands at ``[layer, ...]`` of the stacked
+buffer in place, and attention reads its layer out of the stack once
+(:meth:`CachePlan.layer_view`).  A layer's cache taken out of the stack
+and written back whole would cost a copy of the layer each way.
+
 ``apply_attention`` / ``apply_mla`` are thin executors over the plan:
 they own projections, RoPE, and the prefill softmax (which runs on the
 full-precision values computed in-layer, never on the cache), while the
@@ -235,7 +242,14 @@ class CachePlan:
     # ``new`` carries the layer's full-precision values under their
     # LOGICAL names: {"k", "v"} (B, S, KH, D) for GQA families,
     # {"ckv"} (B, S, r) + {"krope"} (B, S, rope) for MLA families
-    # (decode passes one-token values without the S axis).
+    # (decode passes one-token values without the S axis).  ``layer``
+    # (traced scalar) marks ``cache`` as the stack of every layer's
+    # leaves; None marks one layer's cache.
+
+    def layer_view(self, cache: dict, layer: jax.Array | None) -> dict:
+        """Layer ``layer`` of a stacked cache (``cache`` itself when
+        ``layer`` is None): what decode attention reads."""
+        return {k: kvq.layer_of(v, layer) for k, v in cache.items()}
 
     def _mask_new(self, new: dict, start_pos, prompt_len) -> dict:
         """Zero rows at absolute positions ``>= prompt_len`` (bucket-pad
@@ -253,7 +267,8 @@ class CachePlan:
         return out
 
     def write_prefill(self, cache: dict, new: dict,
-                      prompt_len: jax.Array | None = None) -> dict:
+                      prompt_len: jax.Array | None = None,
+                      layer: jax.Array | None = None) -> dict:
         """Whole-prompt write at position 0 (quantize-on-insert for the
         int8 families, one-shot scales over the real prompt)."""
         if self.paged is not None:
@@ -262,25 +277,26 @@ class CachePlan:
                 "stages prompts in a contiguous stream cache and the "
                 "pool manager scatters whole blocks at insert")
         if not self.quantized:
-            return {k: lax.dynamic_update_slice_in_dim(cache[k], v, 0, 1)
+            return {k: _put_seq(cache[k], v, 0, layer)
                     for k, v in new.items()}
         new = self._mask_new(new, 0, prompt_len)
         out = {}
         for key, x in new.items():
             q, scale = kvq.quantize_kv_prefill(x)
-            out[key + "_q"] = lax.dynamic_update_slice_in_dim(
-                cache[key + "_q"], q, 0, 1)
-            out[key + "_scale"] = scale
+            out[key + "_q"] = _put_seq(cache[key + "_q"], q, 0, layer)
+            out[key + "_scale"] = kvq.with_layer(cache[key + "_scale"],
+                                                 scale, layer)
         return out
 
     def write_chunk(self, cache: dict, new: dict, start_pos: jax.Array,
-                    prompt_len: jax.Array | None = None
-                    ) -> tuple[dict, dict]:
+                    prompt_len: jax.Array | None = None,
+                    layer: jax.Array | None = None) -> tuple[dict, dict]:
         """Chunk write at a sequence offset.  Returns ``(new_cache,
-        views)`` where ``views`` holds the full-precision whole-pool
-        attend views under the logical names (the written pool for
-        full-width families, the dequantized pool for int8 — serve
-        stages chunked prompts full-precision instead, for exactness).
+        views)`` where ``views`` holds the layer's full-precision
+        whole-pool attend views under the logical names (the written
+        pool for full-width families, the dequantized pool for int8 —
+        serve stages chunked prompts full-precision instead, for
+        exactness).
         Pad rows beyond ``prompt_len`` (the chunk's real end) are zeroed
         at the write for BOTH dtypes: a later chunk's bucket is not
         guaranteed to overwrite them before they become attendable.
@@ -291,43 +307,52 @@ class CachePlan:
                 "stages into a contiguous stream cache")
         new = self._mask_new(new, start_pos, prompt_len)
         if not self.quantized:
-            out = {k: lax.dynamic_update_slice_in_dim(cache[k], v,
-                                                      start_pos, 1)
+            out = {k: _put_seq(cache[k], v, start_pos, layer)
                    for k, v in new.items()}
-            return out, out
+            # the view is the layer as read before the write, with the
+            # chunk put in: read from the written stack, the stack would
+            # take attention's (KH, S) order, relaid out whole at entry
+            # and back at exit
+            views = {k: lax.dynamic_update_slice_in_dim(
+                         kvq.layer_of(cache[k], layer), v, start_pos, 1)
+                     for k, v in new.items()}
+            return out, views
         out, views = {}, {}
         for key, x in new.items():
-            q, scale = kvq.kv_write_chunk(cache[key + "_q"],
-                                          cache[key + "_scale"], x,
-                                          start_pos)
-            out[key + "_q"] = q
-            out[key + "_scale"] = scale
+            kq, ks = key + "_q", key + "_scale"
+            q, scale = kvq.kv_write_chunk(kvq.layer_of(cache[kq], layer),
+                                          kvq.layer_of(cache[ks], layer),
+                                          x, start_pos)
+            out[kq] = kvq.with_layer(cache[kq], q, layer)
+            out[ks] = kvq.with_layer(cache[ks], scale, layer)
             views[key] = kvq.dequantize_kv(q, scale, x.dtype)
         return out, views
 
-    def write_decode(self, cache: dict, new: dict,
-                     cache_pos: jax.Array) -> dict:
+    def write_decode(self, cache: dict, new: dict, cache_pos: jax.Array,
+                     layer: jax.Array | None = None) -> dict:
         """One-token scatter at per-slot positions ``cache_pos`` (B,).
         ``new`` values carry no S axis: (B, KH, D) / (B, r).  Int8
         families take the incremental running-max scale update
         (:func:`repro.quant.kv.kv_write_token`)."""
         if self.paged is not None:
-            return self._write_decode_paged(cache, new, cache_pos)
+            return self._write_decode_paged(cache, new, cache_pos, layer)
         bidx = jnp.arange(cache_pos.shape[0])
         if not self.quantized:
-            return {k: cache[k].at[bidx, cache_pos].set(v)
+            return {k: cache[k].at[kvq.layer_index(layer, bidx,
+                                                   cache_pos)].set(v)
                     for k, v in new.items()}
         out = {}
         for key, x in new.items():
             q, scale = kvq.kv_write_token(cache[key + "_q"],
                                           cache[key + "_scale"], x,
-                                          cache_pos)
+                                          cache_pos, layer)
             out[key + "_q"] = q
             out[key + "_scale"] = scale
         return out
 
     def _write_decode_paged(self, cache: dict, new: dict,
-                            cache_pos: jax.Array) -> dict:
+                            cache_pos: jax.Array,
+                            layer: jax.Array | None) -> dict:
         """One-token scatter through the block tables: the target block
         is ``tables[slot, pos // bs]`` and the row ``pos % bs``.  Slots
         whose table row still points at the dummy block (idle) write
@@ -337,7 +362,7 @@ class CachePlan:
         block, never the shared prefix blocks — which are never the
         write target: decode always lands past the shared prefix)."""
         geom = self.paged
-        bt = cache["block_tables"]
+        bt = kvq.layer_of(cache["block_tables"], layer)
         bidx = jnp.arange(cache_pos.shape[0])
         blk = jnp.minimum(cache_pos // geom.block_size,
                           geom.blocks_per_slot - 1)
@@ -346,18 +371,21 @@ class CachePlan:
         # must land in the dummy, not clamp into the slot's last block
         phys = jnp.where(cache_pos < geom.max_seq, phys, geom.dummy_block)
         row = cache_pos % geom.block_size
-        out = {"block_tables": bt}
+        out = {"block_tables": cache["block_tables"]}
         if not self.quantized:
             for key, x in new.items():
-                out[key] = cache[key].at[phys, row].set(
-                    x.astype(cache[key].dtype))
+                out[key] = cache[key].at[
+                    kvq.layer_index(layer, phys, row)].set(
+                        x.astype(cache[key].dtype))
             return out
+        at = kvq.layer_index(layer, phys)
         for key, x in new.items():
-            blk = cache[key + "_q"][phys]                 # (B, bs, KH, D)
-            sc = cache[key + "_scale"][phys]              # (B, KH, D)
+            kq, ks = key + "_q", key + "_scale"
+            blk = cache[kq][at]                           # (B, bs, KH, D)
+            sc = cache[ks][at]                            # (B, KH, D)
             blk, sc = kvq.kv_write_token(blk, sc, x, row)
-            out[key + "_q"] = cache[key + "_q"].at[phys].set(blk)
-            out[key + "_scale"] = cache[key + "_scale"].at[phys].set(sc)
+            out[kq] = cache[kq].at[at].set(blk)
+            out[ks] = cache[ks].at[at].set(sc)
         return out
 
     # -- decode attention (the cache-coupled read) --------------------------
@@ -457,6 +485,16 @@ class CachePlan:
         return fn(q_lat, q_rope, cache["ckv_q"], cache["ckv_scale"],
                   cache["krope_q"], cache["krope_scale"], cache_pos,
                   scale=scale)
+
+
+def _put_seq(buf: jax.Array, x: jax.Array, start,
+             layer: jax.Array | None) -> jax.Array:
+    """Write ``x (B, S', ...)`` into ``buf`` at sequence offset ``start``
+    — of layer ``layer`` of a stacked ``buf``, in place."""
+    if layer is None:
+        return lax.dynamic_update_slice_in_dim(buf, x, start, 1)
+    idx = (layer, 0, start) + (0,) * (x.ndim - 2)
+    return lax.dynamic_update_slice(buf, x[None], idx)
 
 
 def gqa_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,

@@ -73,7 +73,7 @@ def init_block(pb: ParamBuilder, cfg, *, moe: bool) -> None:
 
 def apply_block(p: dict, x: jax.Array, cfg, *, positions, cache=None,
                 cache_pos=None, prompt_len=None, start_pos=None,
-                cache_plan=None, opts: BlockOpts = BlockOpts()
+                cache_plan=None, layer=None, opts: BlockOpts = BlockOpts()
                 ) -> tuple[jax.Array, Any, jax.Array]:
     """Pre-norm block.  Returns (x', new_cache, aux_loss).
 
@@ -81,7 +81,9 @@ def apply_block(p: dict, x: jax.Array, cfg, *, positions, cache=None,
     positions ``[start_pos, start_pos + S)`` and K/V land at the offset
     in the existing cache slot (see ``attention.apply_attention``).
     ``cache_plan`` is the layer's :class:`repro.layers.cache.CachePlan`
-    (classified from the cache keys when None).
+    (classified from the cache keys when None).  ``layer`` (traced
+    scalar) marks ``cache`` as the stack of every layer's caches, which
+    the attention writes in place and returns whole.
     """
     _, norm = _norm_fns(cfg)
     causal = not cfg.is_encoder
@@ -91,7 +93,7 @@ def apply_block(p: dict, x: jax.Array, cfg, *, positions, cache=None,
         a, new_cache = attn.apply_mla(
             p["mla"], h, cfg, positions=positions, causal=causal,
             cache=cache, cache_pos=cache_pos, prompt_len=prompt_len,
-            start_pos=start_pos, plan=cache_plan,
+            start_pos=start_pos, plan=cache_plan, layer=layer,
             opts=opts.attn(cfg.attn_logit_softcap))
     elif "merged" in p:
         a = attn.apply_merged_attention(
@@ -104,7 +106,7 @@ def apply_block(p: dict, x: jax.Array, cfg, *, positions, cache=None,
             num_kv_heads=cfg.num_kv_heads, head_dim=cfg.resolved_head_dim,
             rope_theta=cfg.rope_theta, positions=positions, causal=causal,
             cache=cache, cache_pos=cache_pos, prompt_len=prompt_len,
-            start_pos=start_pos, plan=cache_plan,
+            start_pos=start_pos, plan=cache_plan, layer=layer,
             opts=opts.attn(cfg.attn_logit_softcap))
     x = x + a
     with jax.named_scope(tracing.NORM):

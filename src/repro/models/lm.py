@@ -18,7 +18,12 @@ layers):
                      each application has its own KV cache slot).
 
 Caches are pytrees stacked along each segment's scan axis, so prefill and
-decode run under the same scans.
+decode run under the same scans.  The flat stack of dense / encoder / moe
+blocks carries its stacked cache through the scan and each layer writes
+its new rows into it in place (see :meth:`LMModel._trunk`); the vlm,
+ssm and hybrid scans take their caches as ``xs`` and stack each layer's
+new cache as ``ys``, and the moe ``first`` block, outside the scan, takes
+its own per-layer cache.
 """
 from __future__ import annotations
 
@@ -234,17 +239,27 @@ class LMModel:
         new_cache: dict | None = {} if cache is not None else None
 
         def scan_attn_stack(x, stack_p, stack_cache):
+            """Scan the stacked blocks with the stacked cache in the
+            carry: layer ``l`` writes its new K/V rows at ``[l, ...]``
+            of the carried buffer in place and attends over layer ``l``
+            read back from it.  Taken as ``xs`` and returned as ``ys``,
+            each layer's cache would be copied out of the stack and
+            written back whole, every step."""
+            n = jax.tree.leaves(stack_p)[0].shape[0]
+
             def body(carry, xs):
-                h, aux = carry
-                p_l, c_l = xs
-                h, nc, a = B.apply_block(p_l, h, cfg, positions=positions,
-                                         cache=c_l, cache_pos=cache_pos,
-                                         prompt_len=prompt_len,
-                                         start_pos=start_pos,
-                                         cache_plan=cache_plan, opts=opts)
-                return (h, aux + a), nc
-            (x, aux), ncs = lax.scan(wrap(body), (x, aux_total * 0),
-                                     (stack_p, stack_cache))
+                h, aux, c = carry
+                p_l, layer = xs
+                h, c, a = B.apply_block(p_l, h, cfg, positions=positions,
+                                        cache=c, cache_pos=cache_pos,
+                                        prompt_len=prompt_len,
+                                        start_pos=start_pos,
+                                        cache_plan=cache_plan, layer=layer,
+                                        opts=opts)
+                return (h, aux + a, c), None
+            (x, aux, ncs), _ = lax.scan(
+                wrap(body), (x, aux_total * 0, stack_cache),
+                (stack_p, jnp.arange(n)))
             return x, ncs, aux
 
         if f in ("dense", "encoder", "moe"):

@@ -81,6 +81,30 @@ def _finite_scale(candidate: jax.Array) -> jax.Array:
                        KV_SCALE_MAX)
 
 
+def layer_of(x: jax.Array, layer: jax.Array | None) -> jax.Array:
+    """Layer ``layer`` of a leaf stacked ``(L, ...)`` along the layer
+    scan, or ``x`` itself when ``layer`` is None (a per-layer leaf)."""
+    if layer is None:
+        return x
+    return jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)
+
+
+def with_layer(x: jax.Array, value: jax.Array,
+               layer: jax.Array | None) -> jax.Array:
+    """``x`` with layer ``layer`` replaced by ``value``, or ``value``
+    itself when ``layer`` is None — the write twin of :func:`layer_of`."""
+    if layer is None:
+        return value
+    return jax.lax.dynamic_update_index_in_dim(x, value, layer, 0)
+
+
+def layer_index(layer: jax.Array | None, *idx) -> tuple:
+    """An ``.at[...]`` index of per-layer rows ``idx``, prefixed with
+    ``layer`` for a stacked leaf: the scatter then lands in the stacked
+    buffer itself, not in a copy of the layer."""
+    return idx if layer is None else (layer, *idx)
+
+
 def _check_mode(mode: str) -> None:
     if mode not in KV_MODES:
         raise ValueError(
@@ -244,12 +268,15 @@ def quantize_kv_tree(cache: PyTree, prompt_len: jax.Array | None = None
 
 
 def kv_write_token(cache_q: jax.Array, scale: jax.Array, new: jax.Array,
-                   pos: jax.Array) -> tuple[jax.Array, jax.Array]:
+                   pos: jax.Array, layer: jax.Array | None = None
+                   ) -> tuple[jax.Array, jax.Array]:
     """Insert one decoded token's K (or V) into an int8 cache pool.
 
     ``cache_q (B, S, KH, D)`` int8; ``scale (B, KH, D)`` f32;
     ``new (B, KH, D)``; ``pos (B,)`` per-slot write positions.
-    Returns ``(cache_q', scale')``.
+    Returns ``(cache_q', scale')``.  Given ``layer``, ``cache_q`` and
+    ``scale`` are stacked ``(L, ...)`` along the layer scan and only
+    layer ``layer``'s rows are read and written (requant included).
 
     The scale is a per-channel running max: ``scale' = max(scale,
     |new| / 127)``.  Where it grew, the slot's history is requantized at
@@ -261,19 +288,23 @@ def kv_write_token(cache_q: jax.Array, scale: jax.Array, new: jax.Array,
     one token row, not a full pool read-modify-write per step.
     """
     newf = new.astype(jnp.float32)
-    scale_new = jnp.maximum(scale, _finite_scale(jnp.abs(newf) / INT8_QMAX))
+    old = layer_of(scale, layer)
+    scale_new = jnp.maximum(old, _finite_scale(jnp.abs(newf) / INT8_QMAX))
 
     def _requant(c):
         safe = jnp.where(scale_new > 0, scale_new, 1.0)
-        ratio = jnp.where(scale_new > 0, scale / safe, 1.0)
-        return jnp.clip(jnp.round(c.astype(jnp.float32) * ratio[:, None]),
-                        -INT8_QMAX, INT8_QMAX).astype(jnp.int8)
+        ratio = jnp.where(scale_new > 0, old / safe, 1.0)
+        hist = layer_of(c, layer).astype(jnp.float32) * ratio[:, None]
+        hist = jnp.clip(jnp.round(hist), -INT8_QMAX,
+                        INT8_QMAX).astype(jnp.int8)
+        return with_layer(c, hist, layer)
 
-    cache_q = jax.lax.cond(jnp.any(scale_new > scale), _requant,
+    cache_q = jax.lax.cond(jnp.any(scale_new > old), _requant,
                            lambda c: c, cache_q)
     q_new = quantize_kv(newf, scale_new)
-    bidx = jnp.arange(cache_q.shape[0])
-    return cache_q.at[bidx, pos].set(q_new), scale_new
+    bidx = jnp.arange(new.shape[0])
+    return (cache_q.at[layer_index(layer, bidx, pos)].set(q_new),
+            with_layer(scale, scale_new, layer))
 
 
 # ---------------------------------------------------------------------------

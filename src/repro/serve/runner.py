@@ -17,8 +17,11 @@ kinds:
 Prefill token arrays are length-bucketed by the caller, so each segment
 kind compiles once per bucket, not once per prompt length; ``start_pos``
 and ``prompt_len`` ride along as traced scalars.  The chunk entry
-donates the staging cache (in-place stream growth); the whole-pool
-decode cache is NOT donated (the engine aliases it across steps).
+donates the staging cache (in-place stream growth).  The whole-pool
+decode cache is NOT donated: a caller may still hold the cache it
+passed in (the benchmark's planted fault ``bench/faults.py:_unchanged``
+hands it back as the step's result), so the decode program copies the
+pool once on entry and writes that copy in place, layer by layer.
 
 The runner threads the :class:`repro.layers.cache.CachePlan` for each
 segment's cache into the model (static metadata closed over by the

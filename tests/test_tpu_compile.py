@@ -16,15 +16,28 @@ it admits must compile, which is what the shared ``vmem_limit_bytes``
 buys.  The topology is described inside a fixture only (never at
 import), so every xdist worker collects the same tests and only the
 worker that runs this file loads the TPU library.
+
+The last tests compile whole serve programs, the runner's decode step
+and chunk step at 4 layers, and read the compiled text: the layer scan
+must write its carried KV stack in place and read each layer once,
+with no whole-layer copy and no write-back of a layer into the stack.
 """
+import dataclasses
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import registry
+from repro.core import rank_selection, surgery
 from repro.kernels import ops, tpu
+from repro.models import blocks
+from repro.models.api import get_model
+from repro.serve.runner import ModelRunner
 
 # (C, R, S) of minitron-4b's factored linears at compression 2, aligned
 LOWRANK_GEOMETRIES = {
@@ -147,3 +160,130 @@ def test_decode_attention_latent_q_compiles(compiled):
         ((B, S_MAX, lora), i8), ((B, lora), f32),
         ((B, S_MAX, rope), i8), ((B, rope), f32), ((B,), jnp.int32))
     _assert_kernel(exe)
+
+
+# -- the serve programs' layer scan ------------------------------------------
+
+_INSTR = re.compile(r"^(?:ROOT )?%(\S+) = \w+\[([\d,]*)\](\{[^}]*\})? "
+                    r"([\w-]+)\((.*)")
+
+
+def _computations(text):
+    """``({name: [instruction lines]}, entry name)`` of an HLO module."""
+    comps, entry, name = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name = head.group(2)
+            comps[name] = []
+            entry = name if head.group(1) else entry
+        elif line.strip() == "}":
+            name = None
+        elif name:
+            comps[name].append(line.strip())
+    return comps, entry
+
+
+def _instrs(lines):
+    """``(name, dims, layout, opcode, operands...)`` of array results."""
+    for line in lines:
+        m = _INSTR.match(line)
+        if m:
+            name, dims, layout, op, rest = m.groups()
+            yield (name, tuple(int(d) for d in dims.split(",") if d),
+                   layout or "", op, rest)
+
+
+def _reached(comps, name, seen):
+    seen.add(name)
+    for line in comps[name]:
+        for c in re.findall(r"(?:calls|to_apply|body|condition)=%([\w.-]+)",
+                            line):
+            if c not in seen:
+                _reached(comps, c, seen)
+    return seen
+
+
+def _squeeze(dims):
+    return tuple(d for d in dims if d != 1)
+
+
+def _factored(shapes):
+    """The decomposed tree's shapes: every linear the serve path factors
+    becomes ``w0 (C, R)``, ``w1 (R, S)`` at the aligned rank."""
+    if surgery._is_linear_node(shapes):
+        w = shapes["w"]
+        c, s = w.shape[-2:]
+        r = rank_selection.select_rank(c, s, compression=2.0,
+                                       mode="aligned", align=128)
+        if min(c, s) < 256 or r == rank_selection.ORG:
+            return shapes
+        lead = w.shape[:-2]
+        return {"w0": jax.ShapeDtypeStruct((*lead, c, r), w.dtype),
+                "w1": jax.ShapeDtypeStruct((*lead, r, s), w.dtype)}
+    if isinstance(shapes, dict):
+        return {k: _factored(v) if k != "embed" else v
+                for k, v in shapes.items()}
+    return shapes
+
+
+#: arch, step, pool (slots, max_seq): chat's decode pool and longdoc's
+#: chunked-prefill staging cache, both cut to 4 layers
+SERVE_PROGRAMS = {
+    "minitron-4b-decode": ("minitron-4b", "decode", 24, 1536),
+    "mistral-nemo-12b-prefill_chunk": ("mistral-nemo-12b", "prefill_chunk",
+                                       1, 4096),
+}
+
+
+@pytest.mark.parametrize("program", sorted(SERVE_PROGRAMS))
+def test_layer_scan_writes_the_kv_stack_in_place(one_chip, monkeypatch,
+                                                 program):
+    arch, step, slots, max_seq = SERVE_PROGRAMS[program]
+    monkeypatch.setattr(tpu, "interpret", lambda: False)
+    layers = 4
+    cfg = dataclasses.replace(registry.get(arch).full, num_layers=layers)
+    model = get_model(cfg)
+    params = _factored(jax.eval_shape(
+        lambda k: model.init(k)[0], jax.random.PRNGKey(0)))
+    runner = ModelRunner(model, params, blocks.BlockOpts(use_pallas=True),
+                         max_seq=max_seq)
+    put = functools.partial(jax.tree.map, lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    cache = model.cache_spec(slots, max_seq)
+    if step == "decode":
+        exe = runner.jit_decode.lower(put(params), i32(slots, 1), i32(slots),
+                                      put(cache)).compile()
+    else:
+        exe = runner.jit_prefill_chunk.lower(
+            put(params), {"tokens": i32(1, 64)}, put(cache), i32(),
+            i32()).compile()
+
+    comps, entry = _computations(exe.as_text())
+    layer = (slots, max_seq, cfg.num_kv_heads, cfg.resolved_head_dim)
+    stack = (layers, *layer)
+    leaves = len(jax.tree.leaves(cache))
+    bodies = [b for line in comps[entry]
+              for b in re.findall(r"body=%([\w.-]+)", line)]
+    assert bodies, "no layer scan in the compiled program"
+    for body in bodies:
+        # one layer's K/V is only ever staged on core (memory space 1)
+        # as attention's read, never copied in HBM
+        copies = [name for name, dims, layout, op, _ in _instrs(comps[body])
+                  if _squeeze(dims) == _squeeze(layer) and "S(1)" not in layout]
+        assert not copies, f"layer-sized K/V in HBM in the scan: {copies}"
+        # new rows go into the stack; no whole layer is written back
+        for comp in _reached(comps, body, set()):
+            dims_of = {n: d for n, d, *_ in _instrs(comps[comp])}
+            for name, dims, _, op, rest in _instrs(comps[comp]):
+                if op != "dynamic-update-slice":
+                    continue
+                update = dims_of.get(re.findall(r"%([\w.-]+)", rest)[1], ())
+                assert _squeeze(update) != _squeeze(layer), (
+                    f"{name} writes a whole layer back into {dims}")
+    pool_copies = [name for name, dims, _, op, _ in _instrs(comps[entry])
+                   if op in ("copy", "copy-start")
+                   and _squeeze(dims) == _squeeze(stack)]
+    assert len(pool_copies) <= leaves, pool_copies
